@@ -29,6 +29,8 @@ import os
 import sys
 from typing import Dict, List
 
+from repro.launch.compile_cache import enable_compile_cache
+
 OVERRIDE_ENV = "BENCH_CHECK_OVERRIDE"
 
 # suite tag -> module exposing fresh_for_check(baseline_entry) -> entry
@@ -98,6 +100,7 @@ def _check_suite(suite: str, baseline: Dict,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bench", default="BENCH_serving.json",
                     help="committed trajectory file to gate against")

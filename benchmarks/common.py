@@ -21,6 +21,7 @@ from repro.configs.dit import _dit
 from repro.core import CachedDiT, summarize_stats
 from repro.diffusion import sample
 from repro.models import build_model
+from repro.models.dit import unzero_params
 
 # CPU-scale stand-ins mirroring the paper's depth/width ladder (Table 4)
 BENCH_DITS: Dict[str, ModelConfig] = {
@@ -40,20 +41,8 @@ for k in list(BENCH_DITS):
 def build_dit(name: str):
     cfg = BENCH_DITS[name]
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    # adaLN-zero init makes untrained blocks the identity (gates=0), which
-    # would make every cache policy trivially exact; un-zero the modulation
-    # so blocks transform like a trained model's would
-    k = jax.random.PRNGKey(1)
-    params["blocks"]["ada_w"] = 0.05 * jax.random.normal(
-        k, params["blocks"]["ada_w"].shape)
-    params["blocks"]["ada_b"] = 0.2 * jax.random.normal(
-        jax.random.fold_in(k, 1), params["blocks"]["ada_b"].shape)
-    # ... and the zero-init output head (otherwise eps == 0 identically and
-    # every policy is trivially "exact")
-    params["final_w"] = (jax.random.normal(jax.random.fold_in(k, 2),
-                                           params["final_w"].shape)
-                         / cfg.d_model ** 0.5)
+    params = unzero_params(model.init(jax.random.PRNGKey(0)),
+                           jax.random.PRNGKey(1))
     return cfg, model, params
 
 

@@ -18,6 +18,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SUITES = {
     "table1": ("benchmarks.table1_policies", "Table 1/12: policy comparison"),
     "table2": ("benchmarks.table2_ablation", "Table 2/9: STR/SC/MB ablation"),
@@ -53,6 +55,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", "--suite", dest="only", default="",
                     help="comma-separated suite names (default: all)")

@@ -37,6 +37,7 @@ import numpy as np
 from benchmarks.common import build_dit
 from repro.configs.base import FastCacheConfig
 from repro.core import CachedDiT, registered_policies
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import DEFAULT_AUDIT_FRACTION, MetricsCollector
 from repro.serving import (DiffusionRequest, DiffusionServingEngine,
                            ShardedDiffusionEngine, make_serving_mesh,
@@ -471,6 +472,7 @@ def run() -> List[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dit", default="dit-b2")
     ap.add_argument("--policies", default="nocache,fastcache")

@@ -30,6 +30,7 @@ from typing import Dict, List, Sequence
 
 from benchmarks.common import build_dit
 from benchmarks.serving_diffusion import serve_once
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import poisson_trace, summarize_by_steps
 
 
@@ -89,6 +90,7 @@ def run() -> List[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dit", default="dit-b2")
     ap.add_argument("--policy", default="fastcache")

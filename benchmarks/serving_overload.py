@@ -50,6 +50,7 @@ from benchmarks.common import build_dit
 from benchmarks.serving_diffusion import _fresh_trace, append_entry
 from repro.configs.base import FastCacheConfig
 from repro.core import CachedDiT
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import MetricsCollector
 from repro.serving import (DegradationController, DiffusionRequest,
                            DiffusionServingEngine, ShedLevel, SLOScheduler,
@@ -363,6 +364,7 @@ def run() -> List[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dit", default="dit-b2")
     ap.add_argument("--policy", default="fastcache")

@@ -1,12 +1,14 @@
-"""Sharded-serving benchmark driver row: run the topology sweep of
-``benchmarks/serving_diffusion.py --mesh`` on an 8-virtual-device CPU mesh.
+"""Sharded-serving benchmark driver row: the topology sweep of
+``benchmarks/serving_diffusion.py --mesh``, folded into compact CSV rows —
+one row per (data, model) topology with p50/p95 latency, steps/sec and
+parity against the single-device engine.
 
-The parent benchmark process has already initialized jax on a single CPU
-device, and XLA only honors ``--xla_force_host_platform_device_count`` at
-first init — so the sweep runs in a subprocess with the flag set (the same
-pattern as the production-mesh dry-run), then its JSON report is folded
-into compact CSV rows: one row per (data, model) topology with p50/p95
-latency, steps/sec and parity against the single-device engine.
+On a TPU host the sweep runs in this process on the real chips (topologies
+wider than the host are reported as skipped): a chip belongs to one
+process, so a child could not reach it.  Elsewhere it runs on an
+8-virtual-device CPU mesh in a subprocess — the parent benchmark process
+has already initialized jax on a single CPU device, and XLA only honors
+``--xla_force_host_platform_device_count`` at first init.
 
     PYTHONPATH=src python -m benchmarks.run --only serving_sharded
 """
@@ -19,6 +21,8 @@ import sys
 import tempfile
 from typing import List
 
+from repro.launch.compile_cache import enable_compile_cache
+
 TOPOLOGIES = "1x1,4x1,8x1,4x2"
 DEVICES = 8
 
@@ -26,7 +30,29 @@ DEVICES = 8
 def run(*, topologies: str = TOPOLOGIES, requests: int = 8, slots: int = 4,
         steps: int = 6, policy: str = "fastcache", rate: float = 0.25,
         seed: int = 0) -> List[dict]:
+    import jax
+    if jax.default_backend() == "tpu":
+        from benchmarks.serving_diffusion import (benchmark_topologies,
+                                                  parse_topologies)
+        report = benchmark_topologies(
+            topologies=parse_topologies(topologies), policies=(policy,),
+            requests=requests, slots=slots, steps=steps, rate=rate,
+            seed=seed)
+    else:
+        report = _cpu_subprocess_sweep(topologies=topologies,
+                                       requests=requests, slots=slots,
+                                       steps=steps, policy=policy, rate=rate,
+                                       seed=seed)
+    return _rows(report, policy)
+
+
+def _cpu_subprocess_sweep(*, topologies: str, requests: int, slots: int,
+                          steps: int, policy: str, rate: float,
+                          seed: int) -> dict:
     env = dict(os.environ)
+    # virtual devices exist only on the CPU backend; never let the child
+    # reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     kept = [t for t in env.get("XLA_FLAGS", "").split()
             if not t.startswith("--xla_force_host_platform_device_count")]
     env["XLA_FLAGS"] = " ".join(
@@ -50,10 +76,12 @@ def run(*, topologies: str = TOPOLOGIES, requests: int = 8, slots: int = 4,
                 f"serving_diffusion sweep subprocess failed "
                 f"(exit {proc.returncode}); stderr above")
         with open(out_path) as f:
-            report = json.load(f)
+            return json.load(f)
     finally:
         os.unlink(out_path)
 
+
+def _rows(report: dict, policy: str) -> List[dict]:
     rows = []
     for r in report["topologies"]:
         topo = r["topology"]
@@ -84,5 +112,6 @@ def run(*, topologies: str = TOPOLOGIES, requests: int = 8, slots: int = 4,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for row in run():
         print(f"{row['name']},{row['us_per_call']:.1f},\"{row['derived']}\"")

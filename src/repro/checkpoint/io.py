@@ -11,14 +11,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax.tree.flatten_with_path only exists from jax 0.4.38 on; the pinned
-# 0.4.37 ships it under jax.tree_util.
-_flatten_with_path = getattr(jax.tree, "flatten_with_path", None) \
-    or jax.tree_util.tree_flatten_with_path
-
 
 def _flatten(tree) -> Tuple[Dict[str, np.ndarray], Any]:
-    paths_leaves, treedef = _flatten_with_path(tree)
+    paths_leaves, treedef = jax.tree.flatten_with_path(tree)
     arrays = {}
     keys = []
     for i, (path, leaf) in enumerate(paths_leaves):

@@ -2,10 +2,13 @@
 (Peebles & Xie 2023; FastCache paper Table 4).
 
 | Model    | Layers | Hidden | Heads | Params (M) |
-| DiT-S/2  |   6*   |  384   |   6   |  49        |  (*paper Table 4 lists 6)
-| DiT-B/2  |  12    |  768   |  12   | 126        |
-| DiT-L/2  |  24    | 1024   |  16   | 284        |
-| DiT-XL/2 |  28    | 1152   |  18   | 354        |
+| DiT-S/2  |   6*   |  384   |   6   |  17        |  (*paper Table 4 lists 6)
+| DiT-B/2  |  12    |  768   |  12   | 130        |
+| DiT-L/2  |  24    | 1024   |  16   | 458        |
+| DiT-XL/2 |  28    | 1152   |  18   | 675        |
+
+Params (M) is the ``param_defs`` count of these configs (256x256 images as
+32x32x4 latents, 1000 classes), rounded; tests/test_models.py pins it.
 
 DiT blocks: full bidirectional attention over latent patch tokens, adaLN-zero
 conditioning on (timestep, class), MLP ratio 4. vocab_size is unused (no token

@@ -91,6 +91,15 @@ def denoise_step(runner: CachedDiT, params, sched: sch.Schedule, state,
     return x, state
 
 
+# sample()'s jitted step, shared across calls: one compiled program per
+# (runner, scalar guidance) or per runner for per-sample guidance vectors.
+# A fresh jax.jit per call would retrace and recompile the whole DiT step
+# for every solo run (tens of seconds at DiT-XL/2 on a TPU).
+_jit_step = jax.jit(denoise_step, static_argnums=(0,),
+                    static_argnames=("guidance_scale",))
+_jit_step_per_sample = jax.jit(denoise_step, static_argnums=(0,))
+
+
 def sample(runner: CachedDiT, params, key: jax.Array, *, batch: int,
            labels: Optional[jax.Array] = None, num_steps: int = 50,
            guidance_scale: GuidanceLike = 4.0, num_train_steps: int = 1000,
@@ -124,10 +133,11 @@ def sample(runner: CachedDiT, params, key: jax.Array, *, batch: int,
     off = (jnp.zeros((batch,), jnp.int32) if t_offsets is None
            else t_offsets.astype(jnp.int32))
 
-    step_fn = functools.partial(denoise_step, runner,
-                                guidance_scale=guidance_scale)
+    step = denoise_step
     if jit_step:
-        step_fn = jax.jit(step_fn)
+        step = (_jit_step if isinstance(guidance_scale, (int, float))
+                else _jit_step_per_sample)
+    step_fn = functools.partial(step, runner, guidance_scale=guidance_scale)
 
     for i in range(num_steps):
         t = jnp.clip(ts[i] + off, 0, num_train_steps - 1)

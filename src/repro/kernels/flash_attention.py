@@ -73,7 +73,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jax.Array:
+                    bk: int = 128, interpret: bool) -> jax.Array:
     """q: (B, H, Sq, dh); k, v: (B, KVH, Skv, dh). GQA via head grouping;
     query positions are aligned to the END of the KV sequence."""
     b, h, sq, dh = q.shape

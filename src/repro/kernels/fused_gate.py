@@ -46,17 +46,17 @@ def _kernel(x_ref, xp_ref, po_ref, w_ref, b_ref, sig_ref, elig_ref,
         x = x_ref[0].astype(F32)                       # (BC, D)
         xp = xp_ref[0].astype(F32)
         d = x - xp
-        diff_ref[...] += jnp.sum(d * d)[None, None]
-        prev_ref[...] += jnp.sum(xp * xp)[None, None]
+        diff_ref[...] += jnp.sum(d * d).reshape(1, 1, 1)
+        prev_ref[...] += jnp.sum(xp * xp).reshape(1, 1, 1)
 
     @pl.when(p == 1)
     def _():
-        stat = diff_ref[0, 0] / (jnp.maximum(sig_ref[0, 0], 1e-30) * nd)
-        g = (stat <= threshold) & (elig_ref[0, 0] > 0.0)
+        stat = diff_ref[0, 0, 0] / (jnp.maximum(sig_ref[0, 0, 0], 1e-30) * nd)
+        g = (stat <= threshold) & (elig_ref[0, 0, 0] > 0.0)
 
         @pl.when(j == 0)
         def _():
-            gate_ref[...] = jnp.where(g, 1.0, 0.0)[None, None]
+            gate_ref[...] = jnp.where(g, 1.0, 0.0).reshape(1, 1, 1)
 
         # non-gated samples pass through and are overwritten by the real
         # block outside the kernel — skip their MXU work entirely
@@ -80,7 +80,7 @@ def _kernel(x_ref, xp_ref, po_ref, w_ref, b_ref, sig_ref, elig_ref,
 def fused_gate(x: jax.Array, prev_in: jax.Array, prev_out: jax.Array,
                w: jax.Array, b: jax.Array, sigma2: jax.Array,
                eligible: jax.Array, *, threshold: float, gamma: float = 0.5,
-               use_blend: bool = True, bc: int = 0, interpret: bool = True):
+               use_blend: bool = True, bc: int = 0, interpret: bool):
     """x, prev_in, prev_out: (B, C, D); w: (D, D); b: (D,);
     sigma2, eligible: (B,).  Returns (out (B,C,D) in x.dtype, gate (B,) bool,
     diff_sq (B,) f32, prev_sq (B,) f32)."""
@@ -89,8 +89,10 @@ def fused_gate(x: jax.Array, prev_in: jax.Array, prev_out: jax.Array,
     if c % bc:
         raise ValueError(f"motion length {c} not divisible by block {bc}")
     nd = c * d
-    sig = sigma2.astype(F32).reshape(bsz, 1)
-    elig = eligible.astype(F32).reshape(bsz, 1)
+    # per-sample scalars ride as (B, 1, 1): a (1, 1, 1) block then equals
+    # the array's last two dims, which the TPU block-shape rule accepts
+    sig = sigma2.astype(F32).reshape(bsz, 1, 1)
+    elig = eligible.astype(F32).reshape(bsz, 1, 1)
     grid = (bsz, 2, c // bc)
     out, gate, diff, prevsq = pl.pallas_call(
         functools.partial(_kernel, nd=nd, threshold=threshold, gamma=gamma,
@@ -102,21 +104,22 @@ def fused_gate(x: jax.Array, prev_in: jax.Array, prev_out: jax.Array,
             pl.BlockSpec((1, bc, d), lambda i, p, j: (i, j, 0)),
             pl.BlockSpec((d, d), lambda i, p, j: (0, 0)),
             pl.BlockSpec((1, d), lambda i, p, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, p, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, p, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bc, d), lambda i, p, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, p, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, p, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, p, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, c, d), F32),
-            jax.ShapeDtypeStruct((bsz, 1), F32),
-            jax.ShapeDtypeStruct((bsz, 1), F32),
-            jax.ShapeDtypeStruct((bsz, 1), F32),
+            jax.ShapeDtypeStruct((bsz, 1, 1), F32),
+            jax.ShapeDtypeStruct((bsz, 1, 1), F32),
+            jax.ShapeDtypeStruct((bsz, 1, 1), F32),
         ],
         interpret=interpret,
     )(x, prev_in, prev_out, w, b.reshape(1, d), sig, elig)
-    return (out.astype(x.dtype), gate[:, 0] > 0.0, diff[:, 0], prevsq[:, 0])
+    return (out.astype(x.dtype), gate[:, 0, 0] > 0.0, diff[:, 0, 0],
+            prevsq[:, 0, 0])

@@ -19,27 +19,31 @@ INF = jnp.inf
 
 def _kernel(h_ref, out_ref, *, k: int, w: int, d: int):
     h = h_ref[0].astype(F32)                               # (w, D)
-    sq = jnp.sum(h * h, axis=1)
-    dist = (sq[:, None] + sq[None, :]
-            - 2.0 * jax.lax.dot_general(h, h, (((1,), (1,)), ((), ()))))
-    dist = jnp.maximum(dist, 0.0)
+    sq = jnp.sum(h * h, axis=1, keepdims=True)             # (w, 1)
+    g = jax.lax.dot_general(h, h, (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32)    # (w, w)
     ii = jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
+    # the squared norms as a row: the diagonal of the Gram matrix, summed
+    # down its columns (no in-kernel transpose)
+    sq_row = jnp.sum(jnp.where(ii == jj, g, 0.0), axis=0, keepdims=True)
+    dist = jnp.maximum(sq + sq_row - 2.0 * g, 0.0)
     dist = jnp.where(ii == jj, INF, dist)
-    acc = jnp.zeros((w,), F32)
+    # the distance matrix is symmetric, so column j holds token j's
+    # distances: reducing down the sublanes yields a (1, w) row per round
+    acc = jnp.zeros((1, w), F32)
     for _ in range(k):                                     # unrolled K-min
-        mn = jnp.min(dist, axis=1)                         # (w,)
+        mn = jnp.min(dist, axis=0, keepdims=True)          # (1, w)
         acc = acc + mn
-        # mask exactly one argmin occurrence per row
-        is_min = dist == mn[:, None]
-        first = jnp.cumsum(is_min.astype(jnp.int32), axis=1) == 1
-        dist = jnp.where(is_min & first, INF, dist)
+        # mask exactly one argmin occurrence per column (the first)
+        first = jnp.min(jnp.where(dist == mn, ii, w), axis=0, keepdims=True)
+        dist = jnp.where(ii == first, INF, dist)
     out_ref[0] = jnp.exp(-acc / (k * d))   # per-dim normalized (see ref.py)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def knn_density(h: jax.Array, *, k: int = 5,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """h: (n_windows, w, D) -> rho_sp (n_windows, w)."""
     nw, w, d = h.shape
     if not 1 <= k <= w - 1:
@@ -52,7 +56,9 @@ def knn_density(h: jax.Array, *, k: int = 5,
         functools.partial(_kernel, k=k, w=w, d=d),
         grid=(nw,),
         in_specs=[pl.BlockSpec((1, w, d), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nw, w), F32),
+        # (nw, 1, w): a (1, 1, w) block equals the array's last two dims,
+        # which the TPU block-shape rule accepts
+        out_specs=pl.BlockSpec((1, 1, w), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nw, 1, w), F32),
         interpret=interpret,
-    )(h)
+    )(h).reshape(nw, w)

@@ -40,7 +40,7 @@ def _kernel(x_ref, w_ref, b_ref, prev_ref, out_ref, *, gamma: float,
                    static_argnames=("gamma", "bm", "bf", "bk", "interpret"))
 def linear_blend(x: jax.Array, w: jax.Array, b: jax.Array, prev: jax.Array,
                  *, gamma: float = 0.5, bm: int = 128, bf: int = 256,
-                 bk: int = 256, interpret: bool = True) -> jax.Array:
+                 bk: int = 256, interpret: bool) -> jax.Array:
     """x: (M, D); w: (D, F); b: (F,); prev: (M, F) -> (M, F) in f32."""
     m, d = x.shape
     f = w.shape[1]

@@ -1,13 +1,25 @@
 """Jitted public wrappers for the Pallas kernels.
 
 ``interpret`` defaults to auto: compiled Mosaic on TPU, the Pallas
-interpreter elsewhere (CPU CI / this container).  The interpreter executes
-the same kernel bodies, so correctness tests here transfer to TPU.
+interpreter elsewhere (CPU CI).  The interpreter executes the same kernel
+bodies, so correctness tests on CPU transfer to TPU.
+
+The compiler cannot partition a Mosaic kernel over a mesh.  The kernels of
+the serving path (the fused gate and the three token-merge kernels) are
+independent along their leading axis — one sample, or one window of one
+sample — so under a multi-device sharding ctx (``use_sharding``) they run
+per shard through ``shard_map``: leading-axis rows split over the mesh
+axes that carry the activation batch, shared operands (the gate's linear
+map) replicated.
 """
 from __future__ import annotations
 
-import jax
+import functools
 
+import jax
+from jax.sharding import PartitionSpec as P
+
+from repro.distributed.sharding import current_ctx, spec_for
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_gate as _fg
 from repro.kernels import knn_density as _knn
@@ -18,6 +30,19 @@ from repro.kernels import token_merge as _tm
 
 def _auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _rowwise(kernel, rows, shared=()):
+    """``kernel(*rows, *shared)``; per shard of the leading axis under a
+    multi-device sharding ctx (see the module docstring)."""
+    ctx = current_ctx()
+    if ctx is None or ctx.mesh.size == 1:
+        return kernel(*rows, *shared)
+    lead = P(spec_for((rows[0].shape[0],), ("act_batch",), ctx)[0])
+    return jax.shard_map(
+        kernel, mesh=ctx.mesh,
+        in_specs=(lead,) * len(rows) + (P(),) * len(shared),
+        out_specs=lead, check_vma=False)(*rows, *shared)
 
 
 def default_use_fused() -> bool:
@@ -47,9 +72,13 @@ def fused_gate(x, prev_in, prev_out, w, b, sigma2, eligible, *,
                bc: int = 0, interpret=None):
     if interpret is None:
         interpret = _auto_interpret()
-    return _fg.fused_gate(x, prev_in, prev_out, w, b, sigma2, eligible,
-                          threshold=threshold, gamma=gamma,
-                          use_blend=use_blend, bc=bc, interpret=interpret)
+
+    def kernel(x, prev_in, prev_out, sigma2, eligible, w, b):
+        return _fg.fused_gate(x, prev_in, prev_out, w, b, sigma2, eligible,
+                              threshold=threshold, gamma=gamma,
+                              use_blend=use_blend, bc=bc,
+                              interpret=interpret)
+    return _rowwise(kernel, (x, prev_in, prev_out, sigma2, eligible), (w, b))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -63,16 +92,19 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 def knn_density(h, *, k: int = 5, interpret=None):
     if interpret is None:
         interpret = _auto_interpret()
-    return _knn.knn_density(h, k=k, interpret=interpret)
+    return _rowwise(functools.partial(_knn.knn_density, k=k,
+                                      interpret=interpret), (h,))
 
 
 def merge_assign(h, s, *, m: int, interpret=None):
     if interpret is None:
         interpret = _auto_interpret()
-    return _tm.merge_assign(h, s, m=m, interpret=interpret)
+    return _rowwise(functools.partial(_tm.merge_assign, m=m,
+                                      interpret=interpret), (h, s))
 
 
 def unmerge_scatter(merged, assign, *, interpret=None):
     if interpret is None:
         interpret = _auto_interpret()
-    return _tm.unmerge_scatter(merged, assign, interpret=interpret)
+    return _rowwise(functools.partial(_tm.unmerge_scatter,
+                                      interpret=interpret), (merged, assign))

@@ -45,7 +45,7 @@ def _kernel(x_ref, xp_ref, sal_ref, diff_ref, prev_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
 def saliency_delta(x: jax.Array, x_prev: jax.Array, *, bn: int = 128,
-                   bd: int = 512, interpret: bool = True):
+                   bd: int = 512, interpret: bool):
     """x, x_prev: (N, D) -> (saliency (N,), diff_sq (), prev_sq ())."""
     n, d = x.shape
     bn = min(bn, n)

@@ -1,4 +1,7 @@
 import os
+# A CPU-only tool: it runs on virtual host devices and never takes a chip,
+# even on a TPU host (where a second process on the chip fails or hangs).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            + (os.environ.get("REPRO_DRYRUN_DEVICES") or "512")
                            + " " + os.environ.get("XLA_FLAGS", ""))
@@ -8,6 +11,11 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production mesh, print memory/cost analysis, extract collective bytes
 from the partitioned HLO, and write one JSON artifact per combo.
+
+CPU only: the production meshes are virtual host devices (XLA:CPU), so
+the numbers are compile-time analyses of the CPU programs, never chip
+measurements.  To compile for the TPU itself without a chip, use a
+described topology (see tests/test_tpu_compile.py).
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-14b \
         --shape train_4k --mesh single
@@ -27,19 +35,10 @@ import jax
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.configs.shapes import SHAPES
 from repro.distributed.hlo import collective_bytes
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.specs import (Bundle, build_bundle, model_flops,
                                 skip_reason)
 from repro.models import flags as model_flags
-
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a per-program list of dicts on the
-    pinned jax 0.4.37 and a bare dict on newer releases — normalize both."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
 
 # TPU v5e hardware constants (roofline denominators)
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
@@ -62,7 +61,7 @@ def _measure_cost(arch: str, shape_name: str, mesh, num_layers: int,
         jitted = jax.jit(bundle.step_fn, in_shardings=bundle.in_shardings,
                          out_shardings=bundle.out_shardings)
         compiled = jitted.lower(*bundle.args).compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll, _ = collective_bytes(compiled.as_text(), default_trip=1)
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -123,7 +122,7 @@ def _make_mesh(multi_pod: bool, mesh_shape: str = ""):
         dims = tuple(int(x) for x in mesh_shape.split(","))
         axes = ("pod", "data", "model") if len(dims) == 3 else ("data",
                                                                 "model")
-        return jax.make_mesh(dims, axes)
+        return make_mesh(dims, axes)
     return make_production_mesh(multi_pod=multi_pod)
 
 
@@ -164,7 +163,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
                 v = getattr(mem, k, None)
                 if v is not None:
                     mem_rec[k] = int(v)
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         flops = float(cost.get("flops", 0.0))
         bytes_accessed = float(cost.get("bytes accessed", 0.0))
 

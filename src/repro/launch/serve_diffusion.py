@@ -71,8 +71,9 @@ import numpy as np
 from repro.configs import get_config, get_reduced
 from repro.configs.base import FastCacheConfig
 from repro.core import CachedDiT, POLICIES
-from repro.models import build_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
+from repro.models import build_model
 from repro.obs import (MetricsCollector, TraceRecorder, load_calibration,
                        validate_trace)
 from repro.obs import audit as obs_audit
@@ -97,6 +98,7 @@ def parse_mesh(spec: str):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dit-b2")
     ap.add_argument("--reduced", action="store_true")

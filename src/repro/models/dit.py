@@ -28,6 +28,47 @@ def _ln(x):
     return ((xf - mu) * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype)
 
 
+def unzero_params(params, key: jax.Array, *,
+                  rescale_attention: bool = False):
+    """Seeded, non-degenerate stand-ins for trained DiT weights.
+
+    adaLN-zero init makes every untrained block the identity (its gates
+    are 0) and the zero-init output head makes eps == 0 identically, so
+    with ``DiTModel.init`` weights every cache policy is trivially exact
+    and every comparison trivially passes.  This replaces the block
+    modulation and the output head with seeded normals, so blocks
+    transform like a trained model's would.  Leaves keep their dtypes.
+
+    ``rescale_attention`` also brings the head-split attention projections
+    to the scale of their true fan-in (d_model for ``wq``/``wk``/``wv``,
+    heads x head_dim for ``wo``).  The ``fan_in`` initializer reads the
+    fan-in off ``shape[-2]``, which for these projections is the head count
+    (``wq``) or the head width (``wo``): at DiT-XL/2 width the q/k logits
+    come out ~8x too large and the random network is chaotic — a 1e-3
+    input perturbation moves the output by 4e-2 after four blocks, and bf16
+    rounding alone moves it by O(1).  Rescaled, bf16 stays within ~1e-2 of
+    a float32 forward.  Off by default: the benchmarks' recorded
+    trajectories were made with the unscaled weights."""
+    blocks = dict(params["blocks"])
+    d = params["final_w"].shape[0]
+    ada_w = 0.05 * jax.random.normal(key, blocks["ada_w"].shape)
+    ada_b = 0.2 * jax.random.normal(jax.random.fold_in(key, 1),
+                                    blocks["ada_b"].shape)
+    final_w = (jax.random.normal(jax.random.fold_in(key, 2),
+                                 params["final_w"].shape) / d ** 0.5)
+    blocks["ada_w"] = ada_w.astype(blocks["ada_w"].dtype)
+    blocks["ada_b"] = ada_b.astype(blocks["ada_b"].dtype)
+    if rescale_attention:
+        heads = blocks["wq"].shape[-2]                     # (L, d, h, dh)
+        for name, factor in (("wq", heads / d), ("wk", heads / d),
+                             ("wv", heads / d), ("wo", 1.0 / heads)):
+            w = blocks[name]
+            blocks[name] = (w.astype(jnp.float32)
+                            * factor ** 0.5).astype(w.dtype)
+    return {**params, "blocks": blocks,
+            "final_w": final_w.astype(params["final_w"].dtype)}
+
+
 class DiTModel:
     def __init__(self, cfg: ModelConfig):
         if cfg.family != "dit" or cfg.dit is None:

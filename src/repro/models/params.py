@@ -11,11 +11,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-# jax.tree.flatten_with_path only exists from jax 0.4.38 on; the pinned
-# 0.4.37 ships it under jax.tree_util.
-_flatten_with_path = getattr(jax.tree, "flatten_with_path", None) \
-    or jax.tree_util.tree_flatten_with_path
-
 
 class ParamDef(NamedTuple):
     shape: Tuple[int, ...]
@@ -31,7 +26,7 @@ def _is_def(x) -> bool:
 
 def init_params(defs, key: jax.Array, default_dtype: str):
     """Materialize a ParamDef tree. Key is folded per tree-path (order-stable)."""
-    paths_defs, treedef = _flatten_with_path(defs, is_leaf=_is_def)
+    paths_defs, treedef = jax.tree.flatten_with_path(defs, is_leaf=_is_def)
 
     leaves = []
     for path, d in paths_defs:

@@ -12,10 +12,12 @@ the "pure refactor" guarantee and should be called out in the PR):
 
     PYTHONPATH=src:. python tests/golden/generate.py
 
-Determinism scope: bitwise reproducibility is guaranteed for the pinned jax
-version on the same backend (CI: jax[cpu]==0.4.37 on x86-64 Linux).  XLA:CPU
-gemms are reduction-order deterministic per (shape, dtype), which is all the
-fixed-shape runs below exercise.
+Determinism scope: bitwise reproducibility holds only for the jax version
+CI pins (``.github/workflows/ci.yml``) on the same backend (x86-64 Linux).
+XLA:CPU gemms are reduction-order deterministic per (shape, dtype), which is
+all the fixed-shape runs below exercise.  The committed ``policies.npz``
+predates the current pin, and four policies no longer match it bitwise
+(ROADMAP, queue 3).
 """
 from __future__ import annotations
 

@@ -153,3 +153,43 @@ def test_rope_preserves_norm(key):
     r = apply_rope(x, jnp.arange(8)[None], theta=500.0)
     np.testing.assert_allclose(jnp.linalg.norm(x, axis=-1),
                                jnp.linalg.norm(r, axis=-1), rtol=1e-5)
+
+
+def test_dit_params_column_matches_param_defs():
+    """The ``Params (M)`` column of configs/dit.py's table is each config's
+    ``param_defs`` count in millions, rounded."""
+    import re
+
+    from repro.configs import dit as dit_configs
+    from repro.configs import get_config
+    from repro.models.params import count_params
+    rows = re.findall(r"^\| (DiT-\S+)\s*\|[^|]*\|[^|]*\|[^|]*\|\s*(\d+)\s*\|",
+                      dit_configs.__doc__, re.M)
+    assert len(rows) == 4, rows
+    for name, millions in rows:
+        arch = name.lower().replace("/", "")
+        count = count_params(build_model(get_config(arch)).param_defs())
+        assert round(count / 1e6) == int(millions), (name, count)
+
+
+def test_unzero_params_rescales_attention_to_true_fan_in(key):
+    """``rescale_attention`` draws the head-split projections at their true
+    fan-in (d_model into q/k/v, heads x head_dim into wo); the default
+    leaves them as initialized and only un-zeroes modulation and head."""
+    from repro.configs.base import DiTConfig
+    from repro.configs.dit import _dit
+    from repro.models.dit import unzero_params
+    cfg = _dit("t", 1, 256, 4).replace(
+        dtype="float32", dit=DiTConfig(patch_size=2, in_channels=4,
+                                       num_classes=10, image_size=8))
+    params = build_model(cfg).init(key)
+    plain = unzero_params(params, key)
+    scaled = unzero_params(params, key, rescale_attention=True)
+    for name in ("wq", "wk", "wv", "wo"):
+        np.testing.assert_array_equal(plain["blocks"][name],
+                                      params["blocks"][name])
+        std = float(jnp.std(scaled["blocks"][name]))
+        assert abs(std * 256 ** 0.5 - 1.0) < 0.05, (name, std)
+    assert float(jnp.std(plain["blocks"]["ada_w"])) > 0.0
+    np.testing.assert_array_equal(plain["blocks"]["ada_w"],
+                                  scaled["blocks"]["ada_w"])

@@ -23,6 +23,7 @@ from repro.core import CachedDiT, POLICIES
 from repro.distributed.sharding import (ShardingCtx, make_rules,
                                         serve_state_specs,
                                         serve_state_shardings)
+from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.serving import (DiffusionRequest, DiffusionServingEngine,
                            ShardedDiffusionEngine, make_serving_mesh,
@@ -97,11 +98,9 @@ def _assert_same_serving(base_eng, sharded_eng):
     for k in ("blocks_skipped", "blocks_computed", "steps_reused",
               "block_cache_ratio", "engine_steps", "model_steps"):
         assert sa[k] == sb[k], (k, sa[k], sb[k])
-    flat = getattr(jax.tree, "flatten_with_path", None) \
-        or jax.tree_util.tree_flatten_with_path
     tree_a = (base_eng.state, base_eng.plan, base_eng.slot_acc)
     tree_b = (sharded_eng.state, sharded_eng.plan, sharded_eng.slot_acc)
-    for (path, la), lb in zip(flat(tree_a)[0], jax.tree.leaves(tree_b)):
+    for (path, la), lb in zip(jax.tree.flatten_with_path(tree_a)[0], jax.tree.leaves(tree_b)):
         np.testing.assert_array_equal(
             np.asarray(la), np.asarray(lb),
             err_msg=f"state leaf {jax.tree_util.keystr(path)}")
@@ -130,7 +129,7 @@ def test_serve_state_specs_cover_every_leaf(dit, policy):
     cfg, model, params = dit
     runner = CachedDiT(model, FastCacheConfig(), policy=policy)
     state = runner.init_state(4)
-    ctx = ShardingCtx(jax.make_mesh((1, 1), ("data", "model")),
+    ctx = ShardingCtx(make_host_mesh(),
                       make_rules("serve"))
     specs = serve_state_specs(state, ctx, batch=4, layers=runner.L)
     flat_state = jax.tree.leaves(state)
@@ -160,7 +159,7 @@ def test_slot_axis_rank_rules(dit):
 
 def test_serve_plan_specs_shard_slot_rows():
     from repro.distributed.sharding import serve_plan_specs
-    ctx = ShardingCtx(jax.make_mesh((1, 1), ("data", "model")),
+    ctx = ShardingCtx(make_host_mesh(),
                       make_rules("serve"))
     plan = {"ts": jnp.zeros((4, 8), jnp.int32),
             "ts_prev": jnp.zeros((4, 8), jnp.int32),
@@ -346,6 +345,30 @@ def test_model_axis_numerics_guard(dit):
     # backend partitions model>1 correctly: the validated engine must
     # still match the single-device run end to end
     _assert_same_serving(_base(model, params, "fastcache"), eng)
+
+
+@multi_device
+@pytest.mark.parametrize("topo", [(4, 1), (2, 2)])
+def test_fused_kernels_run_per_shard(dit, topo):
+    """The compiler cannot partition a Pallas kernel, so on a mesh the
+    fused gate and the token-merge kernels run per shard of their leading
+    axis (``kernels/ops._rowwise``).  Served through them (interpret mode
+    here), the mesh must serve what the single-device engine serves."""
+    cfg, model, params = dit
+    fc = FastCacheConfig(use_fused_gate=True, merge_enabled=True,
+                         merge_ratio=0.5, merge_window=16)
+    base = DiffusionServingEngine(CachedDiT(model, fc, policy="fastcache"),
+                                  params, max_slots=4, num_steps=STEPS)
+    eng = ShardedDiffusionEngine(CachedDiT(model, fc, policy="fastcache"),
+                                 params, max_slots=4, num_steps=STEPS,
+                                 mesh=make_serving_mesh(*topo))
+    a, b = _run_latents(base), _run_latents(eng)
+    for rid in a:
+        np.testing.assert_allclose(a[rid], b[rid], rtol=1e-5, atol=1e-5,
+                                   err_msg=f"rid={rid}")
+    sa, sb = base.cache_stats(), eng.cache_stats()
+    assert sa["blocks_skipped"] == sb["blocks_skipped"]
+    assert sa["blocks_computed"] == sb["blocks_computed"]
 
 
 @multi_device

@@ -7,12 +7,13 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (ShardingCtx, make_rules, spec_for,
                                         param_shardings, use_sharding)
+from repro.launch.mesh import make_host_mesh
 from repro.models.params import ParamDef
 
 
 @pytest.fixture(scope="module")
 def ctx():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     return ShardingCtx(mesh, make_rules("train"))
 
 
@@ -23,13 +24,13 @@ def test_spec_basic(ctx):
 def test_divisibility_fallback(ctx):
     # 1-device axes divide everything; build a fake larger mesh via rules on
     # a mesh with extent 1 is trivial — exercise the arithmetic directly
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     big = ShardingCtx(mesh, make_rules("train"))
     assert spec_for((504,), ("vocab",), big) in (P("model"), P(None))
 
 
 def test_axis_conflict_drops_second_use():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     rules = make_rules("train")
     rules["a"] = ("model",)
     rules["b"] = ("model",)
@@ -65,7 +66,7 @@ def test_constrain_noop_outside_ctx():
 
 def test_constrain_applies_in_ctx():
     from repro.distributed.sharding import constrain
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     with use_sharding(mesh, make_rules("train")):
         y = constrain(jnp.ones((4, 4)), "act_batch", "act_embed")
         assert y.shape == (4, 4)
@@ -74,7 +75,7 @@ def test_constrain_applies_in_ctx():
 def test_optimizer_shardings_match_structure():
     from repro.launch.specs import optimizer_shardings
     from repro.training.optimizer import Adafactor, AdamW
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     ctx = ShardingCtx(mesh, make_rules("train"))
     defs = {"w": ParamDef((8, 4), ("embed", "ffn")),
             "b": ParamDef((4,), ("ffn",))}
