@@ -1,0 +1,76 @@
+"""What a run hands to the metric readers (``bench/metrics/*.py``), and the
+arithmetic that several of them share."""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, List, Optional
+
+import numpy as np
+
+from bench import flops
+from bench.window import WindowResult
+
+
+@dataclasses.dataclass
+class RunData:
+    cell: Any                         # spec.Cell
+    dims: Any                         # weights.Dims
+    shape: flops.Shape
+    algo: Any                         # reference.Algo
+    window: WindowResult
+    setup_s: float
+    peak: Any = None                  # peaks.Peak; None off the chip
+    memory_peak_bytes: Optional[int] = None
+    trace: Any = None                 # trace_reduce.Summary with --trace 1
+
+
+def latencies_s(run: RunData) -> List[float]:
+    """Due to latents-on-host, per attempted request; one that never
+    finished counts as waiting until the run gave up on it."""
+    w = run.window
+    return [(r.done_t if r.done_t is not None else w.drained_s) - r.due
+            for r in w.requests]
+
+
+def percentile_ms(values: List[float], q: float) -> Optional[float]:
+    if not values:
+        return None
+    return 1e3 * float(np.percentile(np.asarray(values, np.float64), q))
+
+
+def required_flops(run: RunData) -> float:
+    """FLOPs that the requests' steps inside the window needed: each
+    request's counters (harvested at completion, or read from its slot at
+    the close) over its model rows, an unconditional row at a guidance of
+    1 not counted (its counters are taken as half of the pair's)."""
+    w = run.window
+    total = 0.0
+    for r in w.requests:
+        if r.rid in w.in_flight:
+            counters, steps = w.in_flight[r.rid], w.steps_done[r.rid]
+        elif r.done_t is not None and r.done_t <= w.close_s:
+            counters, steps = r.cache, r.steps
+        else:
+            continue
+        share = 0.5 if r.guidance == 1.0 else 1.0
+        total += flops.request(
+            run.shape, run.algo.fastcache, rows=2 * share, steps=steps,
+            computed=share * counters.get("blocks_computed", 0.0),
+            skipped=share * counters.get("blocks_skipped", 0.0))
+    return total
+
+
+def roofline(run: RunData, kernels) -> Optional[float]:
+    """Least time the kernels' traced calls need at the cell's shapes over
+    their device time, in %; None where the trace holds none of them."""
+    t = run.trace
+    if t is None or run.peak is None:
+        return None
+    costs = flops.kernel_costs(run.shape, rows=2 * int(run.cell.config["slots"]))
+    need = spent = 0.0
+    for k in kernels:
+        calls, secs = t.kernels.get(k, (0, 0.0))
+        if calls:
+            need += calls * costs[k].seconds(run.peak)
+            spent += secs
+    return 100.0 * need / spent if spent > 0 else None
