@@ -54,7 +54,7 @@ State-pytree contract with the engines / sharding walker:
   - ``state["stats"]`` holds per-sample ``(B,)`` float32 counters; every
     key present is accumulated per-request by the serving engines.  The
     standard keys are ``blocks_computed / blocks_skipped / steps_reused /
-    motion_frac_sum`` plus the scalar ``steps`` (bumped by the
+    motion_frac_sum / blocks_run`` plus the scalar ``steps`` (bumped by the
     ``CachedDiT`` shell, not by policies);
   - arrays only — the engines donate the whole pytree buffer-for-buffer;
   - ``tokred`` is RESERVED: when the token-compression stage is on,
@@ -211,7 +211,11 @@ class CachePolicy:
 
     def init_stats(self, batch: int) -> Dict[str, jax.Array]:
         """The standard per-sample stat accumulators every policy carries
-        (the serving engines accumulate every (B,) key per request).  With
+        (the serving engines accumulate every (B,) key per request).
+        ``blocks_computed``/``blocks_skipped`` count a row's own cache
+        decisions; ``blocks_run`` counts the DiT blocks the device executed
+        over the row's tokens, which is more wherever a block runs batch-wide
+        for one row that needs it (the excess is work thrown away).  With
         an active TokenReducer the merge stage's token counters join the
         set — (B,) like every stat key, so the engines' per-request
         accumulation and the obs token counters pick them up with no
@@ -221,6 +225,7 @@ class CachePolicy:
             "blocks_skipped": jnp.zeros((batch,), F32),
             "steps_reused": jnp.zeros((batch,), F32),
             "motion_frac_sum": jnp.zeros((batch,), F32),
+            "blocks_run": jnp.zeros((batch,), F32),
             "steps": jnp.zeros((), F32),
         }
         if self.reducer is not None:
@@ -331,7 +336,9 @@ class CachePolicy:
         its cache payload untouched; False recomputes and refreshes it.
         The block stack only runs when at least one sample recomputes.
         ``computed_on_skip`` counts probe blocks (fbcache's block 0)
-        charged to skipped samples.  ``store(out, st, inputs, x_out)``
+        charged to skipped samples; the probe runs for every sample, so
+        ``blocks_run`` counts it for each, plus the whole stack for each
+        when any sample recomputes.  ``store(out, st, inputs, x_out)``
         writes the policy's own payloads into the ``out`` state dict on the
         recompute path (must mask with ``skip`` itself)."""
         def reuse_all(st):
@@ -359,6 +366,8 @@ class CachePolicy:
                                    + skf * (self.L - computed_on_skip))
         stats["steps_reused"] = stats["steps_reused"] + skf
         stats["motion_frac_sum"] = stats["motion_frac_sum"] + (1.0 - skf)
+        stats["blocks_run"] = (stats["blocks_run"] + computed_on_skip
+                               + jnp.where(jnp.all(skip), 0.0, self.L))
         st["stats"] = stats
         return eps, st
 

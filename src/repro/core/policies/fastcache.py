@@ -90,7 +90,8 @@ class FastCache(CachePolicy):
     def _cold_step(self, params, state, x_in, c):
         """Warm-up: one full forward installing the cache payload (the STR
         static bypass is only valid against a real payload)."""
-        x_out, inputs = self._full_forward(params, x_in, c)
+        with jax.named_scope("fastcache.full_forward"):
+            x_out, inputs = self._full_forward(params, x_in, c)
         hidden = jnp.concatenate([inputs, x_out[None]], axis=0)
         eps = self._eps(params, x_out, c)
         st = dict(state)
@@ -99,6 +100,7 @@ class FastCache(CachePolicy):
         st["have_cache"] = jnp.ones_like(state["have_cache"])
         stats = dict(st["stats"])
         stats["blocks_computed"] = stats["blocks_computed"] + float(self.L)
+        stats["blocks_run"] = stats["blocks_run"] + float(self.L)
         stats["motion_frac_sum"] = stats["motion_frac_sum"] + 1.0
         st["stats"] = stats
         return eps, st
@@ -113,21 +115,24 @@ class FastCache(CachePolicy):
         b, n, d = x_in.shape
 
         # ---- STR: token partition (Eqs. 1-2), per-sample
-        if fc.use_str:
-            sal = saliency.token_saliency(x_in, state["prev_tokens_in"])
-            part = saliency.partition_tokens(sal, fc.motion_threshold,
-                                             self.capacity)
-        else:
-            sal = jnp.full((b, n), jnp.inf, F32)
-            part = saliency.partition_tokens(sal, -1.0, n)
-        mfrac = saliency.motion_fraction(part)               # (B,)
+        with jax.named_scope("fastcache.partition"):
+            if fc.use_str:
+                sal = saliency.token_saliency(x_in, state["prev_tokens_in"])
+                part = saliency.partition_tokens(sal, fc.motion_threshold,
+                                                 self.capacity)
+            else:
+                sal = jnp.full((b, n), jnp.inf, F32)
+                part = saliency.partition_tokens(sal, -1.0, n)
+            mfrac = saliency.motion_fraction(part)           # (B,)
 
         # ---- static bypass (Eq. 3) + MB blend with previous final hidden
-        h_static = linear_approx.apply_linear(fcp["W_c"], fcp["b_c"], x_in)
-        if fc.use_mb:
-            h_static = linear_approx.blend(h_static,
-                                           state["prev_hidden"][-1],
-                                           fc.blend_gamma)
+        with jax.named_scope("fastcache.bypass"):
+            h_static = linear_approx.apply_linear(fcp["W_c"], fcp["b_c"],
+                                                  x_in)
+            if fc.use_mb:
+                h_static = linear_approx.blend(h_static,
+                                               state["prev_hidden"][-1],
+                                               fc.blend_gamma)
 
         # ---- motion stream through gated blocks
         xm = saliency.gather_motion(x_in, part)              # (B,C,D)
@@ -142,44 +147,43 @@ class FastCache(CachePolicy):
         use_sc = bool(fc.use_sc)
 
         def body(carry, xs):
-            xm, sig, ini, comp, skip = carry
+            xm, sig, ini, comp, skip, ran = carry
             bp, w_l, b_l, prev_in, prev_out, lidx = xs
-            prev_m = saliency.gather_motion(prev_in, part)
-            prev_om = saliency.gather_motion(prev_out, part)
-            eligible = ini[lidx] & use_sc                    # (B,)
-
-            if self.gate_mode == "global":
-                diff, prevsq = statcache.delta_stats_per_sample(xm, prev_m)
-                do_cache = jnp.broadcast_to(
-                    statcache.gate_decision_global(diff, sig[lidx], nd * b,
-                                                   threshold_g)
-                    & jnp.all(eligible), (b,))
-                approx = linear_approx.apply_linear(w_l, b_l, xm)
-                if fc.use_mb:
-                    approx = linear_approx.blend(approx, prev_om,
-                                                 fc.blend_gamma)
-                out = jnp.where(do_cache[:, None, None], approx, xm)
-            elif self.use_fused:
-                out, do_cache, diff, prevsq = kernel_ops.fused_gate(
-                    xm, prev_m, prev_om, w_l, b_l, sig[lidx], eligible,
-                    threshold=threshold, gamma=fc.blend_gamma,
-                    use_blend=fc.use_mb)
-            else:
-                out, do_cache, diff, prevsq = kernel_ref.fused_gate(
-                    xm, prev_m, prev_om, w_l, b_l, sig[lidx], eligible,
-                    threshold=threshold, gamma=fc.blend_gamma,
-                    use_blend=fc.use_mb)
+            with jax.named_scope("fastcache.gate"):
+                prev_m = saliency.gather_motion(prev_in, part)
+                prev_om = saliency.gather_motion(prev_out, part)
+                eligible = ini[lidx] & use_sc                # (B,)
+                if self.gate_mode == "global":
+                    diff, prevsq = statcache.delta_stats_per_sample(xm,
+                                                                    prev_m)
+                    do_cache = jnp.broadcast_to(
+                        statcache.gate_decision_global(
+                            diff, sig[lidx], nd * b, threshold_g)
+                        & jnp.all(eligible), (b,))
+                    approx = linear_approx.apply_linear(w_l, b_l, xm)
+                    if fc.use_mb:
+                        approx = linear_approx.blend(approx, prev_om,
+                                                     fc.blend_gamma)
+                    out = jnp.where(do_cache[:, None, None], approx, xm)
+                else:
+                    gate_fn = (kernel_ops.fused_gate if self.use_fused
+                               else kernel_ref.fused_gate)
+                    out, do_cache, diff, prevsq = gate_fn(
+                        xm, prev_m, prev_om, w_l, b_l, sig[lidx], eligible,
+                        threshold=threshold, gamma=fc.blend_gamma,
+                        use_blend=fc.use_mb)
 
             # skip the MXU block entirely when every sample caches;
             # otherwise compute it once for the batch and keep cached
             # samples' approx
-            xm_new = jax.lax.cond(
-                jnp.all(do_cache),
-                lambda ops_: ops_[0],
-                lambda ops_: jnp.where(do_cache[:, None, None], ops_[0],
-                                       self.model.block_apply(bp, ops_[1],
-                                                              c)),
-                (out, xm))
+            with jax.named_scope("fastcache.block"):
+                xm_new = jax.lax.cond(
+                    jnp.all(do_cache),
+                    lambda ops_: ops_[0],
+                    lambda ops_: jnp.where(
+                        do_cache[:, None, None], ops_[0],
+                        self.model.block_apply(bp, ops_[1], c)),
+                    (out, xm))
             # keep the motion-stream carry on its slot shards (serving
             # runs this scan under a (data, model) mesh; without the
             # constraint GSPMD is free to gather the carry onto one device
@@ -187,23 +191,30 @@ class FastCache(CachePolicy):
             xm_new = constrain(xm_new, "act_batch", "act_seq", "act_embed")
             # sliding-window variance tracker updates on recompute,
             # per-sample
-            new_sig, _ = statcache.update_sigma(
-                sig[lidx], ini[lidx], diff, nd, fc.background_momentum)
-            sig = sig.at[lidx].set(jnp.where(do_cache, sig[lidx], new_sig))
-            ini = ini.at[lidx].set(jnp.ones_like(ini[lidx]))
+            with jax.named_scope("fastcache.gate"):
+                new_sig, _ = statcache.update_sigma(
+                    sig[lidx], ini[lidx], diff, nd, fc.background_momentum)
+                sig = sig.at[lidx].set(jnp.where(do_cache, sig[lidx],
+                                                 new_sig))
+                ini = ini.at[lidx].set(jnp.ones_like(ini[lidx]))
             dc = do_cache.astype(F32)
             comp = comp + (1.0 - dc)
             skip = skip + dc
+            # the block runs for every row unless every row caches
+            ran = ran + jnp.where(jnp.all(do_cache), 0.0, 1.0)
             # cache payload: this block's input scattered over prev grid
-            new_prev_in = saliency.scatter_motion(prev_in, xm, part)
-            return (xm_new, sig, ini, comp, skip), new_prev_in
+            with jax.named_scope("fastcache.payload"):
+                new_prev_in = saliency.scatter_motion(prev_in, xm, part)
+            return (xm_new, sig, ini, comp, skip, ran), new_prev_in
 
         lidx = jnp.arange(self.L)
-        prev_in_stack = state["prev_hidden"][:-1]            # (L,B,N,D)
-        prev_out_stack = state["prev_hidden"][1:]            # (L,B,N,D)
+        with jax.named_scope("fastcache.payload"):
+            prev_in_stack = state["prev_hidden"][:-1]        # (L,B,N,D)
+            prev_out_stack = state["prev_hidden"][1:]        # (L,B,N,D)
         carry0 = (xm, gate.sigma2, gate.initialized,
-                  jnp.zeros((b,), F32), jnp.zeros((b,), F32))
-        (xm, sig, ini, comp, skip), new_prev_in = jax.lax.scan(
+                  jnp.zeros((b,), F32), jnp.zeros((b,), F32),
+                  jnp.zeros((), F32))
+        (xm, sig, ini, comp, skip, ran), new_prev_in = jax.lax.scan(
             body, carry0,
             (params["blocks"], fcp["W_l"], fcp["b_l"], prev_in_stack,
              prev_out_stack, lidx))
@@ -214,11 +225,14 @@ class FastCache(CachePolicy):
 
         st = dict(state)
         st["prev_tokens_in"] = x_in
-        st["prev_hidden"] = jnp.concatenate([new_prev_in, h_final[None]], 0)
+        with jax.named_scope("fastcache.payload"):
+            st["prev_hidden"] = jnp.concatenate([new_prev_in, h_final[None]],
+                                                0)
         st["gate"] = statcache.GateState(sigma2=sig, initialized=ini)
         stats = dict(st["stats"])
         stats["blocks_computed"] = stats["blocks_computed"] + comp
         stats["blocks_skipped"] = stats["blocks_skipped"] + skip
+        stats["blocks_run"] = stats["blocks_run"] + ran
         stats["motion_frac_sum"] = stats["motion_frac_sum"] + mfrac
         st["stats"] = stats
         return eps, st
@@ -232,7 +246,8 @@ class FastCache(CachePolicy):
         never happened, and a cold sample's match its own solo warm-up
         step."""
         warm = have                                          # (B,)
-        x_out, inputs = self._full_forward(params, x_in, c)
+        with jax.named_scope("fastcache.full_forward"):
+            x_out, inputs = self._full_forward(params, x_in, c)
         hidden = jnp.concatenate([inputs, x_out[None]], axis=0)
         eps_full = self._eps(params, x_out, c)
         eps_fc, st_fc = self._gated_step(params, state, x_in, c)
@@ -260,6 +275,8 @@ class FastCache(CachePolicy):
             warm, stats["blocks_computed"], old["blocks_computed"] + self.L)
         for k in ("blocks_skipped", "steps_reused"):
             stats[k] = jnp.where(warm, stats[k], old[k])
+        # every row ran the full forward and the gated path's blocks
+        stats["blocks_run"] = stats["blocks_run"] + self.L
         stats["motion_frac_sum"] = jnp.where(
             warm, stats["motion_frac_sum"], old["motion_frac_sum"] + 1.0)
         st["stats"] = stats

@@ -52,6 +52,7 @@ class LearnedLayerCache(CachePolicy):
         stats = dict(st["stats"])
         stats["blocks_computed"] = stats["blocks_computed"] + comp
         stats["blocks_skipped"] = stats["blocks_skipped"] + skip
+        stats["blocks_run"] = stats["blocks_run"] + comp
         stats["motion_frac_sum"] = stats["motion_frac_sum"] + 1.0
         st["stats"] = stats
         return eps, st

@@ -23,6 +23,7 @@ class NoCache(CachePolicy):
         st = dict(state)
         stats = dict(st["stats"])
         stats["blocks_computed"] = stats["blocks_computed"] + float(self.L)
+        stats["blocks_run"] = stats["blocks_run"] + float(self.L)
         stats["motion_frac_sum"] = stats["motion_frac_sum"] + 1.0
         st["stats"] = stats
         return eps, st

@@ -95,7 +95,7 @@ class SmoothCache(CachePolicy):
         mask = self.schedule[:, pos]                         # (L, B)
 
         def body(carry, xs):
-            x, comp, skip = carry
+            x, comp, skip, ran = carry
             bp, delta_prev, m_l = xs
             skip_l = m_l & have                              # (B,)
             reuse = x + delta_prev
@@ -113,10 +113,12 @@ class SmoothCache(CachePolicy):
             delta_new = jnp.where(skip_l[:, None, None], delta_prev,
                                   x_new - x)
             sk = skip_l.astype(F32)
-            return (x_new, comp + (1.0 - sk), skip + sk), delta_new
+            ran = ran + jnp.where(jnp.all(skip_l), 0.0, 1.0)
+            return (x_new, comp + (1.0 - sk), skip + sk, ran), delta_new
 
-        (x_out, comp, skip), new_delta = jax.lax.scan(
-            body, (x_in, jnp.zeros((b,), F32), jnp.zeros((b,), F32)),
+        (x_out, comp, skip, ran), new_delta = jax.lax.scan(
+            body, (x_in, jnp.zeros((b,), F32), jnp.zeros((b,), F32),
+                   jnp.zeros((), F32)),
             (params["blocks"], state["prev_delta"], mask))
         eps = self._eps(params, x_out, c)
 
@@ -127,6 +129,7 @@ class SmoothCache(CachePolicy):
         stats = dict(st["stats"])
         stats["blocks_computed"] = stats["blocks_computed"] + comp
         stats["blocks_skipped"] = stats["blocks_skipped"] + skip
+        stats["blocks_run"] = stats["blocks_run"] + ran
         stats["motion_frac_sum"] = stats["motion_frac_sum"] + 1.0
         st["stats"] = stats
         return eps, st

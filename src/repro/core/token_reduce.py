@@ -92,11 +92,13 @@ class TokenReducer:
         for THIS trace only — ``unmerge`` (called from the policy's
         ``_eps`` later in the same traced step) consumes it, and the
         runner clears it when the step returns."""
-        prev = jnp.where(tr["have_prev"][:, None, None],
-                         tr["prev_full"].astype(x_full.dtype), x_full)
-        merged, mm = token_merge.merge_tokens(
-            x_full, prev, window=self.window, keep_ratio=self.keep_ratio,
-            k=self.k, lam=self.lam, use_fused=self.use_fused)
+        with jax.named_scope("merge"):
+            prev = jnp.where(tr["have_prev"][:, None, None],
+                             tr["prev_full"].astype(x_full.dtype), x_full)
+            merged, mm = token_merge.merge_tokens(
+                x_full, prev, window=self.window,
+                keep_ratio=self.keep_ratio, k=self.k, lam=self.lam,
+                use_fused=self.use_fused)
         self._mm = mm
         new_tr = {"prev_full": x_full.astype(self.dtype),
                   "have_prev": jnp.ones_like(tr["have_prev"])}
@@ -108,6 +110,7 @@ class TokenReducer:
         if self._mm is None:
             raise RuntimeError("TokenReducer.unmerge called outside a "
                                "reduce()d step (no MergeMap stashed)")
-        return token_merge.unmerge_tokens(
-            hidden, self._mm, window=self.window, n_tokens=self.n_tokens,
-            use_fused=self.use_fused)
+        with jax.named_scope("unmerge"):
+            return token_merge.unmerge_tokens(
+                hidden, self._mm, window=self.window,
+                n_tokens=self.n_tokens, use_fused=self.use_fused)

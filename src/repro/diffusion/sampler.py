@@ -54,10 +54,10 @@ def denoise_step(runner: CachedDiT, params, sched: sch.Schedule, state,
     per_sample = not isinstance(guidance_scale, (int, float))
     use_cfg = per_sample or guidance_scale != 1.0
     b = x.shape[0]
-    # named_scope phases show up in jax.profiler traces and nest under the
-    # serving engine's per-dispatch TraceAnnotation (obs.tracing), so an
-    # XLA-level profile attributes time to CFG doubling / model eval /
-    # guidance blend / DDIM update by name
+    # named_scope phases become the ops' name paths in the compiled step,
+    # so an XLA-level profile (beside the engine's host spans,
+    # obs.tracing) attributes time to CFG doubling / model eval / guidance
+    # blend / DDIM update by name
     if use_cfg:
         with jax.named_scope("cfg_double"):
             null_label = runner.model.cfg.dit.num_classes

@@ -97,6 +97,7 @@ def fused_gate(x: jax.Array, prev_in: jax.Array, prev_out: jax.Array,
     out, gate, diff, prevsq = pl.pallas_call(
         functools.partial(_kernel, nd=nd, threshold=threshold, gamma=gamma,
                           use_blend=use_blend),
+        name="fused_gate",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bc, d), lambda i, p, j: (i, j, 0)),

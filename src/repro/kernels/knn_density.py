@@ -54,6 +54,7 @@ def knn_density(h: jax.Array, *, k: int = 5,
                          f"w={w}; need 1 <= k <= w-1 = {w - 1}")
     return pl.pallas_call(
         functools.partial(_kernel, k=k, w=w, d=d),
+        name="knn_density",
         grid=(nw,),
         in_specs=[pl.BlockSpec((1, w, d), lambda i: (i, 0, 0))],
         # (nw, 1, w): a (1, 1, w) block equals the array's last two dims,

@@ -99,6 +99,7 @@ def merge_assign(h: jax.Array, s: jax.Array, *, m: int,
     # block-shape rule accepts
     merged, assign, centers = pl.pallas_call(
         functools.partial(_merge_kernel, m=m, w=w, d=d),
+        name="merge_assign",
         grid=(nw,),
         in_specs=[pl.BlockSpec((1, w, d), lambda i: (i, 0, 0)),
                   pl.BlockSpec((1, 1, w), lambda i: (i, 0, 0))],
@@ -134,6 +135,7 @@ def unmerge_scatter(merged: jax.Array, assign: jax.Array, *,
     w = assign.shape[1]
     return pl.pallas_call(
         functools.partial(_unmerge_kernel, m=m, w=w, d=d),
+        name="unmerge_scatter",
         grid=(nw,),
         # the assignment rides as an (nw, w, 1) column so the one-hot is
         # built without an in-kernel transpose; the block equals the

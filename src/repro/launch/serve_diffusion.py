@@ -37,9 +37,10 @@ Observability (see ``src/repro/obs/``): ``--metrics-out prom.txt`` writes
 the Prometheus text exposition at run end, ``--metrics-jsonl m.jsonl`` the
 per-window JSONL trajectory (window size via ``--metrics-window N``, in
 engine steps; default: one window at run end), and ``--trace-out t.json``
-a Chrome/Perfetto trace of the run (open in ``ui.perfetto.dev``) with
-per-request admit/finish spans and per-slot denoise slices annotated with
-the policy's cache decision.
+a Chrome/Perfetto trace of the run (open in ``ui.perfetto.dev``) with the
+engine's spans (``engine.admit``, ``engine.step``, ``engine.harvest`` and
+their parts), per-request admit/finish spans and per-slot denoise slices
+annotated with the policy's cache decision.
 
 ``--audit-fraction 0.03125`` turns on the shadow-compute audit plane
 (``src/repro/obs/audit.py``): a deterministic seeded fraction of serve
@@ -195,8 +196,8 @@ def main() -> None:
                          "window at run end only")
     ap.add_argument("--trace-out", default="",
                     help="write a Chrome/Perfetto trace JSON of the run "
-                         "here (per-request spans, per-slot denoise "
-                         "slices with cache decisions)")
+                         "here (engine spans, per-request spans, per-slot "
+                         "denoise slices with cache decisions)")
     ap.add_argument("--audit-fraction", type=float, default=0.0,
                     help="shadow-audit this fraction of serve steps "
                          "(deterministic seeded schedule; 0 disables the "
@@ -250,7 +251,8 @@ def main() -> None:
     if collector is not None and args.audit_baseline:
         calib = load_calibration(args.audit_baseline)
         collector.set_audit_context(baseline=calib["errors_mean"])
-    tracer = TraceRecorder() if args.trace_out else None
+    tracer = (TraceRecorder(capture_slots=True) if args.trace_out
+              else None)
     if args.mesh:
         data, tp = parse_mesh(args.mesh)
         engine = ShardedDiffusionEngine(

@@ -142,22 +142,31 @@ class DiTModel:
         return temb + yemb
 
     def block_apply(self, bp, x: jax.Array, c: jax.Array) -> jax.Array:
-        """One DiT block. x: (B,N,D); c: (B,D)."""
-        cfg = self.cfg
-        mod = common.fdot(jax.nn.silu(c.astype(F32)).astype(x.dtype),
-                          bp["ada_w"]) + bp["ada_b"]
-        sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
-        h = common.modulate(_ln(x), sh1, sc1)
-        q = common.feinsum("bnd,dhk->bnhk", h, bp["wq"])
-        k = common.feinsum("bnd,dhk->bnhk", h, bp["wk"])
-        v = common.feinsum("bnd,dhk->bnhk", h, bp["wv"])
-        pos = jnp.arange(x.shape[1])
-        o = attention(q, k, v, pos, pos, causal=False)
-        o = common.feinsum("bnhk,hkd->bnd", o, bp["wo"])
-        x = x + g1[:, None, :] * o
-        h = common.modulate(_ln(x), sh2, sc2)
-        h = common.gelu_mlp(h, bp["w_in"], bp["b_in"], bp["w_out"], bp["b_out"])
-        x = x + g2[:, None, :] * h
+        """One DiT block. x: (B,N,D); c: (B,D).
+
+        Its parts carry the named scopes ``adaln`` (the modulation vector
+        and both modulated norms), ``attention`` (q/k/v, attention, output
+        projection, gated residual) and ``mlp`` (MLP and gated residual),
+        so a device trace can put each op's time down to a part."""
+        with jax.named_scope("adaln"):
+            mod = common.fdot(jax.nn.silu(c.astype(F32)).astype(x.dtype),
+                              bp["ada_w"]) + bp["ada_b"]
+            sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
+            h = common.modulate(_ln(x), sh1, sc1)
+        with jax.named_scope("attention"):
+            q = common.feinsum("bnd,dhk->bnhk", h, bp["wq"])
+            k = common.feinsum("bnd,dhk->bnhk", h, bp["wk"])
+            v = common.feinsum("bnd,dhk->bnhk", h, bp["wv"])
+            pos = jnp.arange(x.shape[1])
+            o = attention(q, k, v, pos, pos, causal=False)
+            o = common.feinsum("bnhk,hkd->bnd", o, bp["wo"])
+            x = x + g1[:, None, :] * o
+        with jax.named_scope("adaln"):
+            h = common.modulate(_ln(x), sh2, sc2)
+        with jax.named_scope("mlp"):
+            h = common.gelu_mlp(h, bp["w_in"], bp["b_in"], bp["w_out"],
+                                bp["b_out"])
+            x = x + g2[:, None, :] * h
         return constrain(x, "act_batch", "act_seq", "act_embed")
 
     def final_layer(self, params, x: jax.Array, c: jax.Array) -> jax.Array:

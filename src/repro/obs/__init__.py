@@ -7,8 +7,10 @@ Three planes, three sync disciplines:
   updated with pure ``jnp`` inside the jitted serve_step; the host-side
   ``MetricsCollector`` harvests only at run end / window close.  Zero
   per-step syncs — machine-checked by reprolint's ``obs-discipline``;
-- **tracing** (``obs.tracing``): per-request Chrome/Perfetto trace JSON.
-  Diagnostic mode: host clocks per step, deferred device snapshots;
+- **tracing** (``obs.tracing``): the engine's host spans, on the device
+  trace's clock when a profiler runs, and per-request Chrome/Perfetto
+  trace JSON.  Diagnostic mode: on only with a ``TraceRecorder`` attached;
+  per-slot device snapshots are opt-in;
 - **calibration** (``obs.calibration``): nocache per-layer delta recorder
   for SmoothCache/spectral schedules.  Offline, syncs freely;
 - **audit** (``obs.audit``): the shadow-compute quality plane — on a

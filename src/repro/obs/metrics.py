@@ -105,9 +105,16 @@ SERVE_STEPS = counter(
 ACTIVE_SLOT_STEPS = counter(
     "active_slot_steps_total", "slot-steps carrying a live request")
 BLOCKS_COMPUTED = counter(
-    "blocks_computed_total", "transformer blocks executed")
+    "blocks_computed_total", "transformer blocks a row's cache decision "
+    "computed (active rows)")
 BLOCKS_SKIPPED = counter(
-    "blocks_skipped_total", "transformer blocks served from cache")
+    "blocks_skipped_total", "transformer blocks a row's cache decision "
+    "served from cache (active rows)")
+BLOCKS_RUN = counter(
+    "blocks_run_total", "transformer blocks the device executed over a "
+    "row's tokens (active rows); a block runs batch-wide unless every row "
+    "skips it, so this exceeds blocks_computed_total by the work thrown "
+    "away")
 STEP_REUSES = counter(
     "cache_step_reuses_total", "whole-step cache reuses (active rows)")
 ADMISSIONS = counter(
@@ -195,7 +202,7 @@ SLOT_AUDIT_STEPS = counter(
 
 # device-plane membership for the diffusion serve_step
 DEVICE_COUNTERS = (SERVE_STEPS, ACTIVE_SLOT_STEPS, BLOCKS_COMPUTED,
-                   BLOCKS_SKIPPED, STEP_REUSES)
+                   BLOCKS_SKIPPED, BLOCKS_RUN, STEP_REUSES)
 DEVICE_HISTOGRAMS = (ACTIVE_SLOTS, SKIP_FRACTION)
 DEVICE_PER_SLOT = (SLOT_ACTIVE_STEPS,)
 

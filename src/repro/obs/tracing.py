@@ -1,55 +1,91 @@
-"""Per-request trace events exported as Chrome/Perfetto trace JSON.
+"""The serving engine's spans and per-request trace events, exported as
+Chrome/Perfetto trace JSON.
 
-Tracing is a **diagnostic mode** — unlike the metrics plane it is allowed
-to keep host-side state per engine step (wall-clock stamps around each
-dispatch) and, when per-slot cache attribution is requested, to snapshot
-device accumulators.  Snapshots are *dispatched copies* (``jnp.add(v, 0)``)
-of the donated buffers, fetched only at :meth:`TraceRecorder.finalize`;
-the steady-state zero-transfer invariant is asserted with tracing OFF.
+Tracing is a **diagnostic mode**, on only where an engine is given a
+``TraceRecorder``; with none attached the engine builds no span and
+records nothing.  It is allowed to keep host-side state per engine step.
 
-Event model (Chrome trace-event format, ``displayTimeUnit: ms``):
+**Spans** (``TraceRecorder.span``): a span is a fixed name (step numbers
+and request ids ride its args, never its name) timed on the host with
+``time.perf_counter_ns`` into the in-memory record ``spans``, and written
+at the same time as a ``jax.profiler.TraceAnnotation`` with the same name
+and args, so a profiler trace holds it on the clock of the device's ops.
+The engines open:
 
-- ``ph="X"`` complete events: one per engine step ("serve_step", with
-  active-slot count), plus per-request "request" spans (admit -> finish)
-  on a per-slot track;
-- ``ph="i"`` instant events: "admit" / "finish" markers carrying rid,
-  label, step counts;
-- per-step "denoise" slices on each slot's track, annotated post-hoc with
-  the policy's gate/skip decision for that step (reconstructed by
-  diffing consecutive accumulator snapshots at finalize);
-- ``ph="C"`` counter tracks: the running block-cache ratio and, when the
-  audit plane's per-slot accumulators ride the snapshots, the running
-  mean audited error — rendered by Perfetto as counter plots alongside
-  the slices.
+  engine.admit (rid, slot)           one admission
+    engine.admit.stage               noise, plan rows and their staging
+    engine.admit.dispatch            the fused admission program
+  engine.resume (rid)                re-admission from a snapshot
+  engine.preempt (rid)               a slot checkpointed out
+  engine.step (engine_step, active)  one ``step()`` that runs the model
+    engine.step.prepare              audit flag and the step's host arrays
+    engine.step.dispatch             the serve step's enqueue (JAX
+                                     dispatch is asynchronous: this is
+                                     not the step's device time)
+    engine.harvest (rids)            only on steps where requests finish
+      engine.harvest.fetch           finished latents and counters to the
+                                     host, waiting for the step itself
+      engine.harvest.reset           the freed slots' reset dispatches
 
-Device-side phases (CFG split, eps, guidance blend, DDIM update) are
-annotated with ``jax.named_scope`` in ``diffusion/sampler.py`` and
-``jax.profiler.TraceAnnotation`` here around dispatch, so an XLA-level
-profile (``jax.profiler.trace``) nests under the same names.
+**Per-slot snapshots** (``capture_slots=True``, opt-in): after each step
+the slots' accumulators are copied on the device (``jnp.add(v, 0)`` of
+the donated buffers) and fetched only at :meth:`TraceRecorder.finalize`,
+where consecutive diffs become per-slot "denoise" slices annotated with
+the policy's skip/compute decision and counter tracks (running cache
+ratio, running mean audited error).  They cost a device copy a step.
+
+Chrome export (``displayTimeUnit: ms``): each span as a ``ph="X"`` event
+on the engine-loop track; per-request "request" spans (admit -> finish)
+and ``ph="i"`` "admit" / "finish" markers on a per-slot track; the
+denoise slices and ``ph="C"`` counter tracks when snapshots were taken.
+
+Inside the jitted step, ``jax.named_scope``s name the sampler's phases
+(``diffusion/sampler.py``), the DiT block's parts (``adaln``,
+``attention``, ``mlp``), FastCache's stages (``fastcache.*``) and token
+merging (``merge``, ``unmerge``): they are op metadata only.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-_US = 1e6  # trace timestamps are microseconds
+_US = 1e3  # trace timestamps are microseconds; the clock counts ns
+
+# what an engine opens where no tracer is attached: one shared no-op
+NO_SPAN = contextlib.nullcontext()
+
+
+class Span(NamedTuple):
+    name: str
+    t0: int                 # time.perf_counter_ns at open
+    t1: int                 # ... and at close
+    parent: Optional[int]   # index in TraceRecorder.spans, None at top
+    args: Dict[str, Any]
+
+
+def span(tracer: Optional["TraceRecorder"], name: str, **args):
+    """``tracer.span(name, **args)``, or ``NO_SPAN`` without a tracer."""
+    return NO_SPAN if tracer is None else tracer.span(name, **args)
 
 
 class TraceRecorder:
-    """Collects trace events on the host; ``finalize()`` resolves deferred
-    device snapshots and ``write()`` emits Chrome/Perfetto JSON."""
+    """Collects spans and trace events on the host; ``finalize()`` resolves
+    deferred slot snapshots and ``write()`` emits Chrome/Perfetto JSON."""
 
-    def __init__(self, *, pid: int = 0, capture_slots: bool = True):
+    def __init__(self, *, pid: int = 0, capture_slots: bool = False):
         self.pid = pid
         self.capture_slots = capture_slots
+        # in opening order; a span still open is None
+        self.spans: List[Optional[Span]] = []
         self.events: List[Dict[str, Any]] = []
-        self._t0 = time.perf_counter()
-        self._open_steps: List[Dict[str, Any]] = []
+        self._t0 = time.perf_counter_ns()
+        self._open: List[int] = []             # indices of open spans
         self._snapshots: List[Dict[str, Any]] = []  # deferred device copies
         self._requests: Dict[int, Dict[str, Any]] = {}
         self._finalized = False
@@ -57,7 +93,15 @@ class TraceRecorder:
     # -- clocks ---------------------------------------------------------
 
     def _now(self) -> float:
-        return (time.perf_counter() - self._t0) * _US
+        return (time.perf_counter_ns() - self._t0) / _US
+
+    # -- spans ----------------------------------------------------------
+
+    def span(self, name: str, **args) -> "_SpanCtx":
+        """A context manager timing ``name`` into ``spans`` and, as a
+        ``jax.profiler.TraceAnnotation`` with ``args`` as its stats, into
+        any profiler trace that is running."""
+        return _SpanCtx(self, name, args)
 
     # -- request lifecycle ---------------------------------------------
 
@@ -90,13 +134,7 @@ class TraceRecorder:
                 "args": {"rid": rid, "admit_step": info["admit_step"],
                          "finish_step": engine_step, **(stats or {})}})
 
-    # -- engine steps ---------------------------------------------------
-
-    def step_begin(self, engine_step: int, *, active: int = -1) -> "_Span":
-        """Open a "serve_step" complete event; use as a context manager
-        around the dispatch.  Also opens a ``jax.profiler``
-        TraceAnnotation so XLA profiles align with the exported trace."""
-        return _Span(self, engine_step, active)
+    # -- per-slot snapshots (opt-in) ------------------------------------
 
     def snapshot_slots(self, engine_step: int, active_rows,
                        slot_stats: Dict[str, Any]) -> None:
@@ -187,7 +225,11 @@ class TraceRecorder:
         for tid in tids:
             meta.append({"name": "thread_name", "ph": "M", "pid": self.pid,
                          "tid": tid, "args": {"name": f"slot {tid - 1}"}})
-        return {"traceEvents": meta + self.events,
+        spans = [{"name": s.name, "ph": "X", "ts": (s.t0 - self._t0) / _US,
+                  "dur": max((s.t1 - s.t0) / _US, 0.01), "pid": self.pid,
+                  "tid": 0, "cat": "engine", "args": s.args}
+                 for s in self.spans if s is not None]
+        return {"traceEvents": meta + spans + self.events,
                 "displayTimeUnit": "ms"}
 
     def write(self, path: str) -> None:
@@ -195,27 +237,30 @@ class TraceRecorder:
             json.dump(self.to_json(), f)
 
 
-class _Span:
-    def __init__(self, rec: TraceRecorder, engine_step: int, active: int):
-        self.rec = rec
-        self.engine_step = engine_step
-        self.active = active
-        self._ann = jax.profiler.TraceAnnotation(
-            f"serve_step[{engine_step}]")
+class _SpanCtx:
+    __slots__ = ("rec", "name", "args", "ann", "idx", "parent", "t0")
+
+    def __init__(self, rec: TraceRecorder, name: str, args: Dict[str, Any]):
+        self.rec, self.name, self.args = rec, name, args
 
     def __enter__(self):
-        self.t0 = self.rec._now()
-        self._ann.__enter__()
+        rec = self.rec
+        self.idx = len(rec.spans)
+        self.parent = rec._open[-1] if rec._open else None
+        rec.spans.append(None)                 # filled in at the close
+        rec._open.append(self.idx)
+        self.ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self._ann.__exit__(*exc)
-        self.rec.events.append({
-            "name": "serve_step", "ph": "X", "ts": self.t0,
-            "dur": max(self.rec._now() - self.t0, 0.01),
-            "pid": self.rec.pid, "tid": 0, "cat": "engine",
-            "args": {"engine_step": self.engine_step,
-                     "active_slots": self.active}})
+        t1 = time.perf_counter_ns()
+        self.ann.__exit__(*exc)
+        rec = self.rec
+        rec._open.pop()
+        rec.spans[self.idx] = Span(self.name, self.t0, t1, self.parent,
+                                   self.args)
         return False
 
 

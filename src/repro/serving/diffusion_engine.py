@@ -64,7 +64,7 @@ from repro.diffusion import schedule as sch
 from repro.obs import audit as obs_audit
 from repro.obs import metrics as obs_metrics
 from repro.obs.metrics import MetricsCollector
-from repro.obs.tracing import TraceRecorder
+from repro.obs.tracing import TraceRecorder, span
 from repro.serving.scheduler import (DiffusionRequest, RequestQueue,
                                      SamplingPlan)
 
@@ -276,6 +276,7 @@ class DiffusionServingEngine:
                                   n_act)
         for name, key in ((obs_metrics.BLOCKS_COMPUTED, "blocks_computed"),
                           (obs_metrics.BLOCKS_SKIPPED, "blocks_skipped"),
+                          (obs_metrics.BLOCKS_RUN, "blocks_run"),
                           (obs_metrics.STEP_REUSES, "steps_reused")):
             if key in delta:
                 metrics = obs_metrics.inc(metrics, name,
@@ -438,27 +439,32 @@ class DiffusionServingEngine:
         s = free[0]
         if req.snapshot is not None:
             return self._resume_request(req, s)
-        plan = self.resolve_plan(req)
-        ts_row, prev_row = plan.rows(self.max_steps, self.num_train_steps)
-        self.state, self.x, self.plan, self.slot_acc = self._admit(
-            self.state, self.x, self.plan, self.slot_acc,
-            self._slot_rows(s), jnp.asarray(s, jnp.int32),
-            self._staged_noise(req), *self._staged_plan(ts_row, prev_row),
-            jnp.asarray(plan.guidance_scale, F32))
-        self.slots[s] = req
-        self.slot_step[s] = 0
-        self.slot_budget[s] = plan.num_steps
-        self.slot_label[s] = req.label
-        req.admit_step = self.clock
-        req.queue_wait_steps = max(self.clock - req.arrival_step, 0)
-        if self.collector is not None:
-            self.collector.inc(obs_metrics.ADMISSIONS)
-            self.collector.observe(obs_metrics.QUEUE_WAIT,
-                                   req.queue_wait_steps)
-        if self.tracer is not None:
-            self.tracer.admit(req.rid, s, label=req.label,
-                              num_steps=plan.num_steps,
-                              engine_step=self.clock)
+        tr = self.tracer
+        with span(tr, "engine.admit", rid=req.rid, slot=s):
+            with span(tr, "engine.admit.stage"):
+                plan = self.resolve_plan(req)
+                ts_row, prev_row = plan.rows(self.max_steps,
+                                             self.num_train_steps)
+                args = (self._slot_rows(s), jnp.asarray(s, jnp.int32),
+                        self._staged_noise(req),
+                        *self._staged_plan(ts_row, prev_row),
+                        jnp.asarray(plan.guidance_scale, F32))
+            with span(tr, "engine.admit.dispatch"):
+                self.state, self.x, self.plan, self.slot_acc = self._admit(
+                    self.state, self.x, self.plan, self.slot_acc, *args)
+            self.slots[s] = req
+            self.slot_step[s] = 0
+            self.slot_budget[s] = plan.num_steps
+            self.slot_label[s] = req.label
+            req.admit_step = self.clock
+            req.queue_wait_steps = max(self.clock - req.arrival_step, 0)
+            if self.collector is not None:
+                self.collector.inc(obs_metrics.ADMISSIONS)
+                self.collector.observe(obs_metrics.QUEUE_WAIT,
+                                       req.queue_wait_steps)
+            if tr is not None:
+                tr.admit(req.rid, s, label=req.label,
+                         num_steps=plan.num_steps, engine_step=self.clock)
         return True
 
     def _resume_request(self, req: DiffusionRequest, s: int) -> bool:
@@ -468,9 +474,10 @@ class DiffusionServingEngine:
         re-scaling) happens here — the resumed run must replay the original
         plan bitwise."""
         snap, req.snapshot = req.snapshot, None
-        self.state, self.x, self.plan, self.slot_acc = self._restore(
-            self.state, self.x, self.plan, self.slot_acc, snap,
-            self._slot_rows(s), jnp.asarray(s, jnp.int32))
+        with span(self.tracer, "engine.resume", rid=req.rid):
+            self.state, self.x, self.plan, self.slot_acc = self._restore(
+                self.state, self.x, self.plan, self.slot_acc, snap,
+                self._slot_rows(s), jnp.asarray(s, jnp.int32))
         self.slots[s] = req
         self.slot_step[s] = req.steps_done
         self.slot_budget[s] = req.num_steps
@@ -494,16 +501,17 @@ class DiffusionServingEngine:
         req = self.slots[s]
         if req is None:
             raise ValueError(f"preempt: slot {s} holds no request")
-        req.snapshot = self._snapshot(self.state, self.x, self.plan,
-                                      self.slot_acc, self._slot_rows(s),
-                                      jnp.asarray(s, jnp.int32))
+        with span(self.tracer, "engine.preempt", rid=req.rid):
+            req.snapshot = self._snapshot(self.state, self.x, self.plan,
+                                          self.slot_acc, self._slot_rows(s),
+                                          jnp.asarray(s, jnp.int32))
+            # same convention as completion-free: a freed slot never
+            # carries stale gate/cache state
+            self.state = self._reset(self.state, self._slot_rows(s))
         req.steps_done = int(self.slot_step[s])
         req.preemptions += 1
         self.slots[s] = None
         self.slot_step[s] = -1
-        # same convention as completion-free: a freed slot never carries
-        # stale gate/cache state
-        self.state = self._reset(self.state, self._slot_rows(s))
         if self.collector is not None:
             self.collector.inc(obs_metrics.PREEMPTIONS)
         if self.tracer is not None:
@@ -513,75 +521,85 @@ class DiffusionServingEngine:
     def step(self) -> List[DiffusionRequest]:
         """One engine step: advance all active slots one denoising step.
         Returns the requests that finished on this step (slots freed) —
-        each after its OWN plan's step budget."""
+        each after its OWN plan's step budget.  With a tracer attached the
+        step is the ``engine.step`` span; its ``engine.step.dispatch``
+        child times the serve step's enqueue only (dispatch is
+        asynchronous), and a completion step's wait for the device falls in
+        ``engine.harvest.fetch``."""
         active = np.array([r is not None for r in self.slots])
         self.clock += 1
         if not active.any():            # idle tick: time passes, no compute
             return []
-        # the audit schedule is a host-side hash of the model-step counter:
-        # the jit only ever sees the resulting traced () boolean, so the
-        # sampled schedule never recompiles (and is False forever when the
-        # audit plane is off)
-        audit_now = self._audit_on and obs_audit.audit_mask(
-            self.model_steps, self.audit_fraction, self.audit_seed)
-        aflag = jnp.asarray(audit_now)
-        if self.tracer is not None:
-            with self.tracer.step_begin(self.clock,
-                                        active=int(active.sum())):
+        tr = self.tracer
+        with span(tr, "engine.step", engine_step=self.clock,
+                  active=int(active.sum())):
+            with span(tr, "engine.step.prepare"):
+                # the audit schedule is a host-side hash of the model-step
+                # counter: the jit only ever sees the resulting traced ()
+                # boolean, so the sampled schedule never recompiles (and is
+                # False forever when the audit plane is off)
+                audit_now = self._audit_on and obs_audit.audit_mask(
+                    self.model_steps, self.audit_fraction, self.audit_seed)
+                args = (jnp.asarray(np.where(active, self.slot_step,
+                                             0).astype(np.int32)),
+                        jnp.asarray(self.slot_label), jnp.asarray(active))
+                aflag = jnp.asarray(audit_now)
+            with span(tr, "engine.step.dispatch"):
                 (self.x, self.state, self.acc, self.slot_acc,
                  self.metrics) = self._step(
-                    self.params, self.state, self.x, self.plan,
-                    jnp.asarray(np.where(active,
-                                         self.slot_step, 0).astype(np.int32)),
-                    jnp.asarray(self.slot_label), jnp.asarray(active),
+                    self.params, self.state, self.x, self.plan, *args,
                     self.acc, self.slot_acc, self.metrics, aflag)
-            self.tracer.snapshot_slots(self.clock, active, self.slot_acc)
-        else:
-            (self.x, self.state, self.acc, self.slot_acc,
-             self.metrics) = self._step(
-                self.params, self.state, self.x, self.plan,
-                jnp.asarray(np.where(active,
-                                     self.slot_step, 0).astype(np.int32)),
-                jnp.asarray(self.slot_label), jnp.asarray(active), self.acc,
-                self.slot_acc, self.metrics, aflag)
-        self.model_steps += 1
+            if tr is not None:
+                tr.snapshot_slots(self.clock, active, self.slot_acc)
+            self.model_steps += 1
 
-        finished: List[DiffusionRequest] = []
-        done_slots = []
-        for s in np.flatnonzero(active):
-            self.slot_step[s] += 1
-            if self.slot_step[s] >= self.slot_budget[s]:
-                done_slots.append(int(s))
-        if done_slots:
+            done_slots = []
+            for s in np.flatnonzero(active):
+                self.slot_step[s] += 1
+                if self.slot_step[s] >= self.slot_budget[s]:
+                    done_slots.append(int(s))
+            if not done_slots:
+                return []
+            with span(tr, "engine.harvest",
+                      rids=[self.slots[s].rid for s in done_slots]):
+                return self._finish(done_slots)
+
+    def _finish(self, done_slots: List[int]) -> List[DiffusionRequest]:
+        """Harvest and free the slots whose requests ended this step."""
+        tr = self.tracer
+        with span(tr, "engine.harvest.fetch"):
             self._harvest(done_slots)
+        finished: List[DiffusionRequest] = []
+        for s in done_slots:
+            req = self.slots[s]
+            req.finish_step = self.clock
+            req.done = True
+            if req.cache is not None:
+                # control-plane accounting rides the harvested counters
+                # (plain host floats — the sharded engine's deferred
+                # materialization passes them through unchanged)
+                req.cache["queue_wait_steps"] = float(
+                    max(req.queue_wait_steps, 0))
+                req.cache["preemptions"] = float(req.preemptions)
+            if self.collector is not None:
+                self.collector.inc(obs_metrics.REQUESTS_FINISHED)
+                self.collector.observe(obs_metrics.REQUEST_LATENCY,
+                                       req.finish_step - req.arrival_step)
+                if (req.deadline_step is not None
+                        and req.finish_step > req.deadline_step):
+                    self.collector.inc(obs_metrics.DEADLINE_MISSES)
+            if tr is not None:
+                tr.finish(req.rid, engine_step=self.clock)
+            finished.append(req)
+            # free immediately: a freed slot is reset below as well as on
+            # admission, so it never carries stale gate/cache state
+            self.slots[s] = None
+            self.slot_step[s] = -1
+        # (the reset leaves the padding row cold, so the next step pays one
+        # mixed warm-up; a stale-cache-free slot table is worth that
+        # once-per-completion cost)
+        with span(tr, "engine.harvest.reset"):
             for s in done_slots:
-                req = self.slots[s]
-                req.finish_step = self.clock
-                req.done = True
-                if req.cache is not None:
-                    # control-plane accounting rides the harvested counters
-                    # (plain host floats — the sharded engine's deferred
-                    # materialization passes them through unchanged)
-                    req.cache["queue_wait_steps"] = float(
-                        max(req.queue_wait_steps, 0))
-                    req.cache["preemptions"] = float(req.preemptions)
-                if self.collector is not None:
-                    self.collector.inc(obs_metrics.REQUESTS_FINISHED)
-                    self.collector.observe(obs_metrics.REQUEST_LATENCY,
-                                           req.finish_step - req.arrival_step)
-                    if (req.deadline_step is not None
-                            and req.finish_step > req.deadline_step):
-                        self.collector.inc(obs_metrics.DEADLINE_MISSES)
-                if self.tracer is not None:
-                    self.tracer.finish(req.rid, engine_step=self.clock)
-                finished.append(req)
-                # free immediately: reset on free as well as on admission,
-                # so a freed slot never carries stale gate/cache state
-                self.slots[s] = None
-                self.slot_step[s] = -1
-                # (the reset leaves the padding row cold, so the next step
-                # pays one mixed warm-up; a stale-cache-free slot table is
-                # worth that once-per-completion cost)
                 self.state = self._reset(self.state, self._slot_rows(s))
         return finished
 
@@ -673,6 +691,7 @@ class DiffusionServingEngine:
             "blocks_skipped": skipped,
             "blocks_computed": computed,
             "block_cache_ratio": skipped / tot if tot else 0.0,
+            "blocks_run": acc("blocks_run"),
             "steps_reused": acc("steps_reused"),
             "per_slot_blocks_skipped": per_slot("blocks_skipped"),
             "per_slot_blocks_computed": per_slot("blocks_computed"),

@@ -255,7 +255,9 @@ class ShardedDiffusionEngine(DiffusionServingEngine):
             return super()._harvest(done_slots)
         # deferred: enqueue device-side row copies (the donated next step
         # cannot clobber them — the runtime orders the copy before reuse)
-        # and materialize once after the trace drains
+        # and materialize once after the trace drains; the
+        # engine.harvest.fetch span around this call so times the enqueue
+        # only, and the fetch itself falls in finalize_requests
         for s in done_slots:
             self.slots[s].latents = self.x[s]
             self.slots[s].cache = {k: v[s]

@@ -234,15 +234,15 @@ def test_jsonl_windows():
 # ---------------------------------------------------------------------------
 
 def test_trace_recorder_round_trip(tmp_path):
-    rec = TraceRecorder()
+    rec = TraceRecorder(capture_slots=True)
     rec.admit(0, 0, label=3, num_steps=4, engine_step=0)
     acc0 = {"steps_reused": jnp.zeros((2,), jnp.float32)}
     acc1 = {"steps_reused": jnp.array([1.0, 0.0], jnp.float32)}
     active = np.array([True, False])
-    with rec.step_begin(1, active=1):
+    with rec.span("engine.step", engine_step=1, active=1):
         pass
     rec.snapshot_slots(1, active, acc0)
-    with rec.step_begin(2, active=1):
+    with rec.span("engine.step", engine_step=2, active=1):
         pass
     rec.snapshot_slots(2, active, acc1)
     rec.finish(0, engine_step=2, stats={"steps_reused": 1.0})
@@ -250,7 +250,7 @@ def test_trace_recorder_round_trip(tmp_path):
     validate_trace(doc)
     names = [e["name"] for e in doc["traceEvents"]]
     assert "admit" in names and "finish" in names
-    assert "request rid=0" in names and "serve_step" in names
+    assert "request rid=0" in names and "engine.step" in names
     # slot 0's accumulator moved between snapshots -> a cache-reuse slice
     assert "denoise (cache reuse)" in names
     assert doc["displayTimeUnit"] == "ms"
@@ -280,7 +280,7 @@ def test_trace_counter_tracks():
     """Perfetto counter tracks (ph="C") from the cumulative snapshots:
     the running cache ratio always, the running mean audit error when the
     audit plane's accumulators ride the slot stats."""
-    rec = TraceRecorder()
+    rec = TraceRecorder(capture_slots=True)
     active = np.array([True, True])
     snaps = [
         {"blocks_computed": jnp.array([4.0, 4.0]),
@@ -304,7 +304,7 @@ def test_trace_counter_tracks():
     assert ratios == [0.0, 4.0 / 16.0]
     assert errs[0] == 0.0 and np.isclose(errs[1], 0.4 / 4.0)
     # without audit accumulators only the cache-ratio track is emitted
-    rec2 = TraceRecorder()
+    rec2 = TraceRecorder(capture_slots=True)
     rec2.snapshot_slots(0, active,
                        {"blocks_computed": jnp.array([4.0, 4.0])})
     names = [e["name"] for e in rec2.to_json()["traceEvents"]

@@ -102,7 +102,7 @@ EXPECTED_STATE = {
 }
 
 STD_STATS = {"blocks_computed", "blocks_skipped", "steps_reused",
-             "motion_frac_sum", "steps"}
+             "motion_frac_sum", "blocks_run", "steps"}
 
 
 @pytest.mark.parametrize("policy", POLICIES)

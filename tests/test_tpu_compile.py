@@ -153,3 +153,38 @@ def test_serving_kernels_compile_per_shard_on_a_mesh(topo, monkeypatch):
         s((ROWS,), F32), s((ROWS,), jnp.bool_), s((N_WIN, WIN, D), BF16),
         s((N_WIN, WIN), F32))
     assert txt.count("tpu_custom_call") >= 2
+
+
+def test_serving_kernels_are_named_for_the_benchmark(one_chip, monkeypatch):
+    """Each serving-path kernel's custom call in a compiled fastcache step
+    with token merging carries the name the benchmark's trace reduction
+    looks for (``bench/trace_reduce.KERNELS``, matched on the HLO
+    instruction name without its ``.<n>`` suffix): a renamed kernel fails
+    here instead of silencing ``merge_roofline``."""
+    import re
+
+    from bench.trace_reduce import KERNELS
+    monkeypatch.setattr(kernel_ops, "_auto_interpret", lambda: False)
+    cfg = get_config("dit-xl2").replace(num_layers=2)
+    model = build_model(cfg)
+    runner = CachedDiT(model, FastCacheConfig(use_fused_gate=True,
+                                              merge_enabled=True,
+                                              merge_ratio=0.5),
+                       policy="fastcache")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    dit = cfg.dit
+    txt = _compiled_text(
+        runner.step, on_chip(model.abstract_params()),
+        on_chip(jax.eval_shape(lambda: runner.init_state(ROWS))),
+        on_chip(jax.ShapeDtypeStruct(
+            (ROWS, dit.image_size, dit.image_size, dit.in_channels), F32)),
+        on_chip(jax.ShapeDtypeStruct((ROWS,), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((ROWS,), jnp.int32)))
+    names = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_][A-Za-z0-9_\-]*)(?:\.\d+)* = [^\n]*custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', txt)}
+    assert names == set(KERNELS)
