@@ -7,6 +7,14 @@ the full per-block input-hidden stack (H_{t-1,l-1} of Eq. 4 — the cache
 payload the linear approximators blend against), the chi^2 sliding-window
 variance trackers, and the warm-up flag.  No cached eps: fastcache gates
 per-block, never per-step.
+
+The payload stack, (L+1, B, N, D), is updated in place.  A gated step
+reads it only at the motion tokens (each layer gathered once) and writes
+only there: block l's input over layer l where the tau_s gate kept the
+token, then the reassembled final hidden over layer L.  Every other token
+keeps last step's value, which it already holds.  The step relies on the
+serving engine donating the state: the scan writes into the donated
+buffer, and without the donation XLA copies the stack once per step.
 """
 from __future__ import annotations
 
@@ -146,12 +154,25 @@ class FastCache(CachePolicy):
             threshold_g = statcache.make_threshold(fc.alpha, nd * b)
         use_sc = bool(fc.use_sc)
 
+        # cache payload: block l reads last step's motion tokens of layers
+        # l (its input) and l+1 (its output); each layer is gathered once,
+        # layer l+1 by block l, which hands it on to block l+1
+        with jax.named_scope("fastcache.payload"):
+            keep = saliency.motion_keep(part)[..., None]     # (B,C,1)
+            prev_m0 = saliency.gather_motion_layer(state["prev_hidden"], 0,
+                                                   part)
+            # the scan writes into the old stack's buffer, so every read of
+            # it outside the scan is ordered first (else XLA copies it)
+            h_static, prev_m0, hidden0 = jax.lax.optimization_barrier(
+                (h_static, prev_m0, state["prev_hidden"]))
+
         def body(carry, xs):
-            xm, sig, ini, comp, skip, ran = carry
-            bp, w_l, b_l, prev_in, prev_out, lidx = xs
+            xm, prev_m, hidden, sig, ini, comp, skip, ran = carry
+            bp, w_l, b_l, lidx = xs
+            with jax.named_scope("fastcache.payload"):
+                prev_om = saliency.gather_motion_layer(hidden, lidx + 1,
+                                                       part)
             with jax.named_scope("fastcache.gate"):
-                prev_m = saliency.gather_motion(prev_in, part)
-                prev_om = saliency.gather_motion(prev_out, part)
                 eligible = ini[lidx] & use_sc                # (B,)
                 if self.gate_mode == "global":
                     diff, prevsq = statcache.delta_stats_per_sample(xm,
@@ -202,22 +223,20 @@ class FastCache(CachePolicy):
             skip = skip + dc
             # the block runs for every row unless every row caches
             ran = ran + jnp.where(jnp.all(do_cache), 0.0, 1.0)
-            # cache payload: this block's input scattered over prev grid
+            # cache payload, in place: this block's input over its motion
+            # tokens (those the tau_s gate kept); every other token of
+            # layer l keeps last step's value, which it already holds
             with jax.named_scope("fastcache.payload"):
-                new_prev_in = saliency.scatter_motion(prev_in, xm, part)
-            return (xm_new, sig, ini, comp, skip, ran), new_prev_in
+                hidden = saliency.set_motion_layer(
+                    hidden, lidx, jnp.where(keep, xm, prev_m), part)
+            return (xm_new, prev_om, hidden, sig, ini, comp, skip, ran), None
 
         lidx = jnp.arange(self.L)
-        with jax.named_scope("fastcache.payload"):
-            prev_in_stack = state["prev_hidden"][:-1]        # (L,B,N,D)
-            prev_out_stack = state["prev_hidden"][1:]        # (L,B,N,D)
-        carry0 = (xm, gate.sigma2, gate.initialized,
+        carry0 = (xm, prev_m0, hidden0, gate.sigma2, gate.initialized,
                   jnp.zeros((b,), F32), jnp.zeros((b,), F32),
                   jnp.zeros((), F32))
-        (xm, sig, ini, comp, skip, ran), new_prev_in = jax.lax.scan(
-            body, carry0,
-            (params["blocks"], fcp["W_l"], fcp["b_l"], prev_in_stack,
-             prev_out_stack, lidx))
+        (xm, _, hidden, sig, ini, comp, skip, ran), _ = jax.lax.scan(
+            body, carry0, (params["blocks"], fcp["W_l"], fcp["b_l"], lidx))
 
         # ---- reassemble full grid (concat of Eq. 2 sets)
         h_final = saliency.scatter_motion(h_static, xm, part)
@@ -226,8 +245,8 @@ class FastCache(CachePolicy):
         st = dict(state)
         st["prev_tokens_in"] = x_in
         with jax.named_scope("fastcache.payload"):
-            st["prev_hidden"] = jnp.concatenate([new_prev_in, h_final[None]],
-                                                0)
+            st["prev_hidden"] = hidden.at[self.L].set(
+                h_final.astype(hidden.dtype))
         st["gate"] = statcache.GateState(sigma2=sig, initialized=ini)
         stats = dict(st["stats"])
         stats["blocks_computed"] = stats["blocks_computed"] + comp

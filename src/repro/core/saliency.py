@@ -42,7 +42,8 @@ def partition_tokens(saliency: jax.Array, tau_s: float,
 
 def gather_motion(x: jax.Array, part: Partition) -> jax.Array:
     """(B,N,D) -> (B,C,D) motion-token stream (saliency-descending order)."""
-    return jnp.take_along_axis(x, part.motion_idx[..., None], axis=1)
+    return jnp.take_along_axis(x, part.motion_idx[..., None], axis=1,
+                               mode="promise_in_bounds")
 
 
 def scatter_motion(base: jax.Array, motion: jax.Array,
@@ -50,12 +51,42 @@ def scatter_motion(base: jax.Array, motion: jax.Array,
     """Write the motion stream back over `base` at its token positions,
     but only where the tau_s gate marked the token as true motion."""
     b = base.shape[0]
-    keep = jnp.take_along_axis(part.is_motion, part.motion_idx, axis=-1)
+    keep = motion_keep(part)
     updated = base.at[jnp.arange(b)[:, None], part.motion_idx].set(
         jnp.where(keep[..., None], motion,
                   jnp.take_along_axis(base, part.motion_idx[..., None],
                                       axis=1)))
     return updated
+
+
+def motion_keep(part: Partition) -> jax.Array:
+    """(B, C) bool: which of the top-C tokens the tau_s gate kept as
+    motion, in ``motion_idx`` order."""
+    return jnp.take_along_axis(part.is_motion, part.motion_idx, axis=-1)
+
+
+def gather_motion_layer(stack: jax.Array, layer,
+                        part: Partition) -> jax.Array:
+    """(K,B,N,D) -> (B,C,D): the motion tokens of layer ``layer`` of a
+    layer stack."""
+    return gather_motion(
+        jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False), part)
+
+
+def set_motion_layer(stack: jax.Array, layer, rows: jax.Array,
+                     part: Partition) -> jax.Array:
+    """Write (B,C,D) ``rows`` over layer ``layer`` of a (K,B,N,D) stack at
+    the motion tokens' positions, leaving every other token untouched.
+
+    The layer is sliced out, scattered and written back whole: on a TPU
+    v5e a scatter of rows straight into the stack in HBM runs row by row,
+    over 3x slower than slicing the layer into fast memory, scattering
+    there and writing it back.  Mapped over the batch, so a stack sharded
+    on it is written shard by shard."""
+    cur = jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    cur = jax.vmap(lambda c, i, r: c.at[i].set(r, unique_indices=True))(
+        cur, part.motion_idx, rows.astype(stack.dtype))
+    return jax.lax.dynamic_update_index_in_dim(stack, cur, layer, 0)
 
 
 def motion_fraction(part: Partition) -> jax.Array:

@@ -11,7 +11,11 @@ from repro.configs.base import FastCacheConfig
 from repro.core import (CachedDecoder, CachedDiT, chi2_ppf, error_bound,
                         summarize_stats)
 from repro.core import linear_approx, saliency, statcache, token_merge
+from repro.core.policies.base import F32
+from repro.core.policies.fastcache import FastCache
+from repro.kernels import ref as kernel_ref
 from repro.models import build_model
+from repro.models.dit import unzero_params
 from tests.conftest import f32_cfg
 
 
@@ -272,6 +276,126 @@ def test_fastcache_output_close_to_nocache(key):
            for a, b in zip(outs_nc, outs_fc)]
     # Eq. 9-style bounded deviation (loose engineering bound)
     assert max(rel) < 1.5, rel
+
+
+class _CopyingFastCache(FastCache):
+    """FastCache with the payload update it had before the in-place one:
+    the stack sliced into block inputs and outputs, each block's inputs
+    scattered over a full copy of its layer, and the copies concatenated
+    with the final hidden.  The reference for the in-place update."""
+
+    def _gated_step(self, params, state, x_in, c):
+        fc, fcp = self.fc, self.fc_params
+        b = x_in.shape[0]
+        sal = saliency.token_saliency(x_in, state["prev_tokens_in"])
+        part = saliency.partition_tokens(sal, fc.motion_threshold,
+                                         self.capacity)
+        h_static = linear_approx.blend(
+            linear_approx.apply_linear(fcp["W_c"], fcp["b_c"], x_in),
+            state["prev_hidden"][-1], fc.blend_gamma)
+        xm = saliency.gather_motion(x_in, part)
+        nd = int(xm.shape[1] * xm.shape[2])
+        threshold = statcache.make_threshold(fc.alpha, nd)
+        threshold_g = statcache.make_threshold(fc.alpha, nd * b)
+
+        def body(carry, xs):
+            xm, sig, ini, comp, skip, ran = carry
+            bp, w_l, b_l, prev_in, prev_out, lidx = xs
+            prev_m = saliency.gather_motion(prev_in, part)
+            prev_om = saliency.gather_motion(prev_out, part)
+            eligible = ini[lidx]
+            if self.gate_mode == "global":
+                diff, _ = statcache.delta_stats_per_sample(xm, prev_m)
+                do_cache = jnp.broadcast_to(
+                    statcache.gate_decision_global(
+                        diff, sig[lidx], nd * b, threshold_g)
+                    & jnp.all(eligible), (b,))
+                approx = linear_approx.blend(
+                    linear_approx.apply_linear(w_l, b_l, xm), prev_om,
+                    fc.blend_gamma)
+                out = jnp.where(do_cache[:, None, None], approx, xm)
+            else:
+                out, do_cache, diff, _ = kernel_ref.fused_gate(
+                    xm, prev_m, prev_om, w_l, b_l, sig[lidx], eligible,
+                    threshold=threshold, gamma=fc.blend_gamma)
+            xm_new = jax.lax.cond(
+                jnp.all(do_cache), lambda o: o[0],
+                lambda o: jnp.where(do_cache[:, None, None], o[0],
+                                    self.model.block_apply(bp, o[1], c)),
+                (out, xm))
+            new_sig, _ = statcache.update_sigma(
+                sig[lidx], ini[lidx], diff, nd, fc.background_momentum)
+            sig = sig.at[lidx].set(jnp.where(do_cache, sig[lidx], new_sig))
+            ini = ini.at[lidx].set(jnp.ones_like(ini[lidx]))
+            dc = do_cache.astype(F32)
+            ran = ran + jnp.where(jnp.all(do_cache), 0.0, 1.0)
+            return ((xm_new, sig, ini, comp + 1.0 - dc, skip + dc, ran),
+                    saliency.scatter_motion(prev_in, xm, part))
+
+        gate = state["gate"]
+        (xm, sig, ini, comp, skip, ran), new_prev_in = jax.lax.scan(
+            body, (xm, gate.sigma2, gate.initialized, jnp.zeros((b,), F32),
+                   jnp.zeros((b,), F32), jnp.zeros((), F32)),
+            (params["blocks"], fcp["W_l"], fcp["b_l"],
+             state["prev_hidden"][:-1], state["prev_hidden"][1:],
+             jnp.arange(self.L)))
+        h_final = saliency.scatter_motion(h_static, xm, part)
+        st = dict(state)
+        st["prev_tokens_in"] = x_in
+        st["prev_hidden"] = jnp.concatenate([new_prev_in, h_final[None]], 0)
+        st["gate"] = statcache.GateState(sigma2=sig, initialized=ini)
+        stats = dict(st["stats"])
+        stats["blocks_computed"] = stats["blocks_computed"] + comp
+        stats["blocks_skipped"] = stats["blocks_skipped"] + skip
+        stats["blocks_run"] = stats["blocks_run"] + ran
+        stats["motion_frac_sum"] = (stats["motion_frac_sum"]
+                                    + saliency.motion_fraction(part))
+        st["stats"] = stats
+        return self._eps(params, h_final, c), st
+
+
+@pytest.mark.parametrize("gate_mode", ["per_sample", "global"])
+def test_fastcache_in_place_payload_matches_copying_update(gate_mode):
+    """The in-place payload update leaves eps and the whole state (payload
+    stack, gate trackers, counters) bitwise where the copying update does,
+    step after step: gated steps on which the tau gate leaves some of the
+    top-C tokens static, and a mixed step after a mid-flight admission."""
+    cfg = get_reduced("dit-b2").replace(num_layers=4)
+    model = build_model(cfg)
+    params = unzero_params(model.init(jax.random.PRNGKey(0)),
+                           jax.random.PRNGKey(1))
+    fc = FastCacheConfig(gate_mode=gate_mode, use_fused_gate=False)
+    runner = CachedDiT(model, fc, policy="fastcache")
+    ref = CachedDiT(model, fc, policy="fastcache")
+    ref.impl = _CopyingFastCache(model, fc, ref.fc_params,
+                                 gate_mode=gate_mode, use_fused=False)
+    b, img, ch = 4, cfg.dit.image_size, cfg.dit.in_channels
+    key = jax.random.PRNGKey(2)
+    x = jax.random.normal(key, (b, img, img, ch))
+    labels = jnp.arange(b)
+    step, step_ref = jax.jit(runner.step), jax.jit(ref.step)
+    state = runner.init_state(b)
+    state_ref = ref.init_state(b)
+    for i in range(7):
+        if i == 4:       # a request admitted into row 1: a mixed step
+            state = runner.reset_slot(state, jnp.array([1]))
+            state_ref = ref.reset_slot(state_ref, jnp.array([1]))
+        t = jnp.full((b,), 40 - 3 * i)
+        eps, state = step(params, state, x, t, labels)
+        eps_ref, state_ref = step_ref(params, state_ref, x, t, labels)
+        np.testing.assert_array_equal(np.asarray(eps), np.asarray(eps_ref))
+        jax.tree.map(lambda a, r: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(r)), state, state_ref)
+        # only the top rows of the latents move, by a step-dependent
+        # amount: the tokens of the other rows are static
+        noise = jax.random.normal(jax.random.fold_in(key, i), x.shape)
+        x = x.at[:, :img // 4].add((0.5 if i % 2 else 0.02)
+                                   * noise[:, :img // 4])
+    s = summarize_stats(state)
+    # the tau gate left top-C tokens static (motion below the capacity)
+    assert s["mean_motion_fraction"] < fc.motion_capacity, s
+    # and the gate both cached and computed blocks
+    assert 0.0 < s["block_cache_ratio"] < 1.0, s
 
 
 def test_l2c_respects_mask(key):

@@ -10,7 +10,9 @@ topology is described inside a module fixture — never at import — because
 only one process may hold the TPU library, and a test worker that collects
 this file must not take it unless it runs these tests.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +128,54 @@ def test_fastcache_step_compiles_at_xl(one_chip, monkeypatch):
     assert "tpu_custom_call" in txt
 
 
+def test_fastcache_gated_step_updates_payload_in_place(one_chip,
+                                                      monkeypatch):
+    """The gated fastcache step (every step of a request after its first)
+    at the 512x512 serving widths: 8 rows, 1024 latent tokens merged to
+    512, motion capacity C 256, d 1152, 28 blocks.  Compiled with the state
+    donated, as the engine does, the payload stack ``prev_hidden`` of the
+    output takes the donated input's buffer, and the step needs less
+    scratch memory than one (L, B, N, D) stack: it touches the stack only
+    at the motion tokens, and copies none of it."""
+    monkeypatch.setattr(kernel_ops, "_auto_interpret", lambda: False)
+    cfg = get_config("dit-xl2")
+    cfg = cfg.replace(dit=dataclasses.replace(cfg.dit, image_size=64))
+    model = build_model(cfg)
+    runner = CachedDiT(model, FastCacheConfig(use_fused_gate=True,
+                                              merge_enabled=True,
+                                              merge_ratio=0.5),
+                       policy="fastcache")
+    policy = runner.impl
+    assert (policy.n_tokens, policy.capacity) == (512, 256)
+    monkeypatch.setattr(policy, "step", policy._gated_step)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    dit = cfg.dit
+    state = jax.eval_shape(lambda: runner.init_state(ROWS))
+    stack = state["prev_hidden"]
+    assert stack.shape == (cfg.num_layers + 1, ROWS, 512, D)
+    compiled = jax.jit(runner.step, donate_argnums=(1,)).lower(
+        on_chip(model.abstract_params()), on_chip(state),
+        on_chip(jax.ShapeDtypeStruct(
+            (ROWS, dit.image_size, dit.image_size, dit.in_channels), F32)),
+        on_chip(jax.ShapeDtypeStruct((ROWS,), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((ROWS,), jnp.int32))).compile()
+    txt = compiled.as_text()
+    dims = ",".join(map(str, stack.shape))
+    entry = txt[txt.index("\nENTRY "):]
+    param = re.search(rf"= bf16\[{dims}\]\S* parameter\((\d+)\)",
+                      entry).group(1)
+    # an input aliases only an output of its own shape, and prev_hidden is
+    # the step's one output of the stack's shape
+    assert re.search(rf"\{{\d+\}}: \({param}, \{{\}}, may-alias\)", txt)
+    layer_stack = (stack.size // stack.shape[0] * cfg.num_layers
+                   * stack.dtype.itemsize)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_stack
+
+
 def test_serving_kernels_compile_per_shard_on_a_mesh(topo, monkeypatch):
     """The compiler refuses to partition a Mosaic kernel ("Mosaic kernels
     cannot be automatically partitioned"); under a (2, 2) serving mesh the
@@ -161,8 +211,6 @@ def test_serving_kernels_are_named_for_the_benchmark(one_chip, monkeypatch):
     looks for (``bench/trace_reduce.KERNELS``, matched on the HLO
     instruction name without its ``.<n>`` suffix): a renamed kernel fails
     here instead of silencing ``merge_roofline``."""
-    import re
-
     from bench.trace_reduce import KERNELS
     monkeypatch.setattr(kernel_ops, "_auto_interpret", lambda: False)
     cfg = get_config("dit-xl2").replace(num_layers=2)
