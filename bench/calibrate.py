@@ -7,9 +7,10 @@ from these readings).
         --control-seeds 11,12,13 --seconds 10
 
 Per seed: the cell's weights and traffic from that seed, a window of
-``--seconds`` at the cell's load through the engine, the sample that a run
-compares, and the reference.  The control is the reference in float8 put
-in the program's place, on the same sample and from the same inputs.
+``--seconds`` at the cell's load through the engines, the sample that a run
+compares, and the model family's reference.  The control is the reference
+in lower precision (``quant=True``; float8 for DiT) put in the program's
+place, on the same sample and from the same inputs.
 Writes ``chiprun_out/calibrate_<name>.json``.
 """
 from __future__ import annotations
@@ -23,33 +24,35 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 
 
-def readings(cell, su, eng, seed: int, seconds: float, control: bool):
+def readings(cell, su, srv, seed: int, seconds: float, control: bool):
     """One seed's row: the program's numbers, and the control's."""
-    from bench import check, loadgen, reference, weights
+    from bench import check, loadgen
     from bench.window import drive
-    cfg, mix, d = cell.config, cell.mix, su.dims
+    cfg, mix, fam, d = cell.config, cell.mix, su.family, su.dims
     slots = int(cfg["slots"])
-    params = weights.make_params(d, seed, cfg["dtype"])
-    eng.params = params           # same shapes: the step does not recompile
-    eng.reset_clock()
-    traffic, depth = loadgen.traffic(mix, seed, seconds, d.classes, slots)
+    params = fam.make_params(d, seed, cfg["dtype"])
+    srv.place(params)             # same shapes: the step does not recompile
+    srv.reset_clock()
+    traffic, depth = loadgen.traffic(mix, seed, seconds, su.conds, slots)
     watch = check.plan(
-        loadgen.candidates(mix, seed, seconds, d.classes, slots),
+        loadgen.candidates(mix, seed, seconds, su.conds, slots),
         int(cfg["check"]["sample"]), seed)
-    w = drive(eng, traffic, seconds, su.counter, backlog_depth=depth,
+    w = drive(srv, traffic, seconds, su.counter, backlog_depth=depth,
               watch=watch)
     sample = check.sampled(w.requests, watch)
-    p32 = reference.to_f32(params)
-    ref = check.reference_outputs(p32, d, su.algo, sample)
-    breaks, unread = check.rule_breaks(sample, su.algo)
+    p32 = fam.to_f32(params)
+    ref = fam.reference_outputs(p32, d, su.algo, sample)
+    breaks, unread = fam.rule_breaks(sample, su.algo)
     row = {"seed": seed, "compared": len(sample),
            "gated_steps": sum(len(check.gated_steps(r)) for r in sample),
            "unread_rows": unread,
-           "program": dict(check.gaps(d, sample, check.served_outputs(sample),
-                                      ref), cache_rule_breaks=breaks)}
+           "program": dict(fam.gaps(d, sample, check.served_outputs(sample),
+                                    ref), cache_rule_breaks=breaks)}
+    if w.replicas:
+        row["replicas"] = w.replicas
     if control:
-        ctl = check.reference_outputs(p32, d, su.algo, sample, quant=True)
-        row["control"] = check.gaps(d, sample, ctl, ref)
+        ctl = fam.reference_outputs(p32, d, su.algo, sample, quant=True)
+        row["control"] = fam.gaps(d, sample, ctl, ref)
     return row
 
 
@@ -62,9 +65,6 @@ def main() -> int:
     args = ap.parse_args()
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-
-    from bench import weights
     from bench.run import serving, set_up
     from bench.spec import load_cell
 
@@ -73,11 +73,11 @@ def main() -> int:
     cfg = cell.config
     control = {int(s) for s in args.control_seeds.split(",") if s}
     seeds = [int(s) for s in args.seeds.split(",")]
-    eng = serving(cfg, weights.make_params(su.dims, seeds[0], cfg["dtype"]),
-                  su.max_steps)
+    srv = serving(cfg, su.family.make_params(su.dims, seeds[0], cfg["dtype"]),
+                  su.max_steps, root=cell.root)
     rows = []
     for seed in seeds:
-        rows.append(readings(cell, su, eng, seed, args.seconds,
+        rows.append(readings(cell, su, srv, seed, args.seconds,
                              seed in control))
         print(json.dumps(rows[-1]), flush=True)
     out = {"workload": cell.name, "device": su.dev.device_kind,
@@ -92,7 +92,7 @@ def main() -> int:
     dest.parent.mkdir(exist_ok=True)
     dest.write_text(json.dumps(out, indent=1))
     print(json.dumps({k: out.get(k) for k in ("program", "control")}))
-    jax.block_until_ready(eng.x)
+    srv.block()
     return 0
 
 

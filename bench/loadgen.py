@@ -10,17 +10,19 @@ holds only parameters:
     steps      {"<DDIM steps>": share, ...}
     guidance   {"<guidance scale>": share, ...}
 
-Labels are uniform over the model's classes and every request has its own
-noise seed, all drawn from the run's seed.
+Every request carries an integer conditioning ``cond``, uniform over
+range(conds), where the model family sets ``conds`` and maps ``cond`` to
+what the program takes (``bench/families/<family>.py``), and a noise seed
+of its own, both drawn from the run's seed.
 
 Every seed gets the same work in another order: the arrival times are one
 draw fixed by the mix and the window (the exponential distribution's
 quantiles at (i + 1/2)/n as the inter-arrival gaps, in an order drawn
 once, their sum scaled to the window), and the seed deals the requests
 onto them: the step budgets and guidance scales, which are the mix's
-shares rounded to whole requests, in its own order, and every label and
-noise seed.  So two seeds differ in which request arrives when, and not
-in how much work arrives or when.
+shares rounded to whole requests, in its own order, and every
+conditioning and noise seed.  So two seeds differ in which request arrives
+when, and not in how much work arrives or when.
 """
 from __future__ import annotations
 
@@ -37,7 +39,7 @@ ARRIVAL_ORDER = 0      # the fixed draw that orders the gaps
 @dataclasses.dataclass(eq=False)
 class Request:
     rid: int
-    label: int
+    cond: int                         # the family's conditioning
     steps: int
     guidance: float
     noise_seed: int
@@ -49,6 +51,7 @@ class Request:
     # step -> copy of the request's slot after it (bench/check.py)
     taps: Dict[int, Dict] = dataclasses.field(default_factory=dict)
     slot: int = -1
+    replica: int = 0                  # the engine that served it
 
 
 def _shares(mix: Dict, key: str, n: int, rng: np.random.Generator,
@@ -89,7 +92,7 @@ def _rate_integral(mix: Dict, t: np.ndarray) -> np.ndarray:
 
 
 def poisson(mix: Dict, seed: int, seconds: float,
-            classes: int) -> List[Request]:
+            conds: int) -> List[Request]:
     """Requests due in [0, seconds), in order of due time."""
     total = float(_rate_integral(mix, np.asarray(seconds)))
     n = max(1, int(round(total)))
@@ -104,14 +107,14 @@ def poisson(mix: Dict, seed: int, seconds: float,
     due = np.interp(op, _rate_integral(mix, grid), grid)
     steps = _shares(mix, "steps", n, rng, int)
     guidance = _shares(mix, "guidance", n, rng, float)
-    labels = rng.integers(0, classes, n)
+    cond = rng.integers(0, conds, n)
     noise = rng.integers(0, 2**31 - 1, n)
-    return [Request(rid=i, label=int(labels[i]), steps=steps[i],
+    return [Request(rid=i, cond=int(cond[i]), steps=steps[i],
                     guidance=guidance[i], noise_seed=int(noise[i]),
                     due=float(due[i])) for i in range(n)]
 
 
-def backlog(mix: Dict, seed: int, classes: int,
+def backlog(mix: Dict, seed: int, conds: int,
             block: int = 20) -> Iterator[Request]:
     """An endless stream; each run of ``block`` requests holds the mix's
     shares exactly."""
@@ -121,30 +124,30 @@ def backlog(mix: Dict, seed: int, classes: int,
         steps = _shares(mix, "steps", block, rng, int)
         guidance = _shares(mix, "guidance", block, rng, float)
         for s, g in zip(steps, guidance):
-            yield Request(rid=next(rid), label=int(rng.integers(classes)),
+            yield Request(rid=next(rid), cond=int(rng.integers(conds)),
                           steps=s, guidance=g,
                           noise_seed=int(rng.integers(0, 2**31 - 1)))
 
 
-def traffic(mix: Dict, seed: int, seconds: float, classes: int,
+def traffic(mix: Dict, seed: int, seconds: float, conds: int,
             slots: int) -> Tuple[Union[List[Request], Iterator[Request]], int]:
     """A window's traffic for ``bench/window.drive``: the open-loop list,
     or the backlog's stream with the number of requests it keeps waiting."""
     if mix["arrival"] == "poisson":
-        return poisson(mix, seed, seconds, classes), 0
+        return poisson(mix, seed, seconds, conds), 0
     if mix["arrival"] == "backlog":
-        return backlog(mix, seed, classes), int(mix.get("depth", slots))
+        return backlog(mix, seed, conds), int(mix.get("depth", slots))
     raise ValueError(f"unknown arrival {mix['arrival']!r}")
 
 
-def candidates(mix: Dict, seed: int, seconds: float, classes: int,
+def candidates(mix: Dict, seed: int, seconds: float, conds: int,
                slots: int) -> List[Request]:
     """The requests a check may sample before the window runs: every one
     due in it (open loop), or the backlog's first two slots' worth, which
     the window admits in its first two rounds."""
     if mix["arrival"] == "backlog":
-        return list(itertools.islice(backlog(mix, seed, classes), 2 * slots))
-    return poisson(mix, seed, seconds, classes)
+        return list(itertools.islice(backlog(mix, seed, conds), 2 * slots))
+    return poisson(mix, seed, seconds, conds)
 
 
 def max_steps(mix: Dict) -> int:

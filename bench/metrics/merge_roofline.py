@@ -1,6 +1,6 @@
 """The three token-merge kernels' share of their roofline together, in %:
-the least time their calls need (bench/flops.py) over their device time
-in the trace."""
+the least time their calls need (the model family's ``kernel_costs``) over
+their device time in the trace."""
 from bench.results import roofline
 
 

@@ -1,7 +1,9 @@
-"""The FLOPs that the window's served work needed (bench/flops.py, from
-each request's block counters: active rows only, a cached block as its
-linear approximation, a first step as every block on every token), over
-the engine-busy time on the host's clock and the chip's bf16 peak, in %.
+"""The FLOPs that the window's served work needed (the model family's
+``request_flops``, from each request's counters at its completion or at
+the close; for DiT ``bench/flops.py``: active rows only, a cached block as
+its linear approximation, a first step as every block on every token),
+over the engine-busy time on the host's clock and the chip's bf16 peak,
+in %.
 The busy time holds the host's gaps between steps, which
 ``device_idle_share`` reads from the trace, so a host stall lowers both."""
 from bench.results import required_flops

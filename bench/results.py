@@ -7,21 +7,21 @@ from typing import Any, List, Optional
 
 import numpy as np
 
-from bench import flops
 from bench.window import WindowResult
 
 
 @dataclasses.dataclass
 class RunData:
     cell: Any                         # spec.Cell
-    dims: Any                         # weights.Dims
-    shape: flops.Shape
-    algo: Any                         # reference.Algo
+    dims: Any                         # the family's sizes
+    shape: Any                        # the family's shape_of(dims, algo)
+    algo: Any                         # the family's algo_of(config)
     window: WindowResult
     setup_s: float
     peak: Any = None                  # peaks.Peak; None off the chip
     memory_peak_bytes: Optional[int] = None
     trace: Any = None                 # trace_reduce.Summary with --trace 1
+    family: Any = None                # the module bench/families/<family>.py
 
 
 def latencies_s(run: RunData) -> List[float]:
@@ -39,10 +39,9 @@ def percentile_ms(values: List[float], q: float) -> Optional[float]:
 
 
 def required_flops(run: RunData) -> float:
-    """FLOPs that the requests' steps inside the window needed: each
-    request's counters (harvested at completion, or read from its slot at
-    the close) over its model rows, an unconditional row at a guidance of
-    1 not counted (its counters are taken as half of the pair's)."""
+    """FLOPs that the requests' steps inside the window needed, by the
+    family's count (``request_flops``) from each request's counters,
+    harvested at completion or read from its slot at the close."""
     w = run.window
     total = 0.0
     for r in w.requests:
@@ -52,11 +51,8 @@ def required_flops(run: RunData) -> float:
             counters, steps = r.cache, r.steps
         else:
             continue
-        share = 0.5 if r.guidance == 1.0 else 1.0
-        total += flops.request(
-            run.shape, run.algo.fastcache, rows=2 * share, steps=steps,
-            computed=share * counters.get("blocks_computed", 0.0),
-            skipped=share * counters.get("blocks_skipped", 0.0))
+        total += run.family.request_flops(run.shape, run.algo, r, counters,
+                                          steps)
     return total
 
 
@@ -66,7 +62,7 @@ def roofline(run: RunData, kernels) -> Optional[float]:
     t = run.trace
     if t is None or run.peak is None:
         return None
-    costs = flops.kernel_costs(run.shape, rows=2 * int(run.cell.config["slots"]))
+    costs = run.family.kernel_costs(run.shape, int(run.cell.config["slots"]))
     need = spent = 0.0
     for k in kernels:
         calls, secs = t.kernels.get(k, (0, 0.0))

@@ -5,10 +5,12 @@
 In order: the device must be a TPU that ``bench/peaks.py`` knows, with as
 many chips as the cell asks for, or the run exits non-zero and prints no
 result; JAX's persistent compilation cache is turned on; the weights are
-made on the device from ``--seed``; every program the window runs is run
-once (set-up ends here, at the first due request); the window serves the
-cell's traffic for ``--seconds``; the outputs are compared with the plain
-reference (``bench/check.py``); the last line of stdout is one JSON object.
+made on the device from ``--seed`` by the configuration's model family
+(``bench/spec.py``); every program the window runs is run once, on every
+replica (set-up ends here, at the first due request); the window serves
+the cell's traffic for ``--seconds``; the outputs are compared with the
+family's plain reference (``bench/check.py``); the last line of stdout is
+one JSON object.
 With ``--trace 1`` the profiler traces the window's last few seconds
 (stopping it stalls the host, so it stops after the drain) and the
 per-layer metrics are reported instead of the end-to-end ones.
@@ -81,55 +83,56 @@ class SetUp(NamedTuple):
     peak: object           # peaks.Peak; None off the chip
     counter: object        # window.CompileCounter
     cache_dir: Optional[str]
-    dims: object           # weights.Dims
-    algo: object           # reference.Algo
+    family: object         # the module bench/families/<family>.py
+    dims: object           # the family's sizes
+    algo: object           # the family's algorithm settings
     max_steps: int
+    conds: object          # what loadgen draws each request's cond from
 
 
 def set_up(cell, require_chip: bool = True) -> SetUp:
-    """The device check, the compile cache and the sizes of ``cell``: what
-    every entry point (this one, ``calibrate.py``, ``sweep.py``) does
-    before it makes weights."""
-    from bench import loadgen, reference, weights
+    """The device check, the compile cache, the model family and the sizes
+    of ``cell``: what every entry point (this one, ``calibrate.py``,
+    ``sweep.py``) does before it makes weights."""
+    from bench import loadgen
+    from bench.spec import family
     from bench.window import CompileCounter
     dev, devs, peak = device_info(cell.chips, require_chip)
     cache_dir = compile_cache() if require_chip else None
-    return SetUp(dev, devs, peak, CompileCounter(), cache_dir,
-                 weights.dims_of(cell.config),
-                 reference.algo_of(cell.config),
-                 loadgen.max_steps(cell.mix))
+    fam = family(cell.config, cell.root)
+    dims = fam.dims_of(cell.config)
+    return SetUp(dev, devs, peak, CompileCounter(), cache_dir, fam, dims,
+                 fam.algo_of(cell.config), loadgen.max_steps(cell.mix),
+                 fam.conds(cell.config, dims))
 
 
-def serving(cfg, params, max_steps: int, engine_hook=None):
-    """The engine of configuration ``cfg``, every program it runs in the
-    window run once."""
-    from bench.window import build, warm_up
-    eng = build(cfg, params, max_steps)
-    if engine_hook is not None:            # tests break the timed path
-        engine_hook(eng)
-    warm_up(eng)
-    return eng
+def serving(cfg, params, max_steps: int, engine_hook=None, root=ROOT):
+    """What configuration ``cfg`` deploys (``window.Server``), every program
+    it runs in the window run once."""
+    from bench.spec import family
+    from bench.window import deploy
+    return deploy(family(cfg, root), cfg, params, max_steps, engine_hook)
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, *,
              t_start: float, require_chip: bool = True, engine_hook=None):
     """One run of ``cell``; returns the result line as a dict."""
-    import jax
     import numpy as np
 
-    from bench import check, flops, loadgen, reference, weights
+    from bench import check, loadgen
     from bench.results import RunData
     from bench.spec import metric_reader
     from bench.window import drive
 
     su = set_up(cell, require_chip)
-    cfg, mix, d, algo = cell.config, cell.mix, su.dims, su.algo
+    cfg, mix, fam, d, algo = (cell.config, cell.mix, su.family, su.dims,
+                              su.algo)
     slots = int(cfg["slots"])
-    params = weights.make_params(d, seed, cfg["dtype"])
-    eng = serving(cfg, params, su.max_steps, engine_hook)
-    traffic, depth = loadgen.traffic(mix, seed, seconds, d.classes, slots)
+    params = fam.make_params(d, seed, cfg["dtype"])
+    srv = serving(cfg, params, su.max_steps, engine_hook, cell.root)
+    traffic, depth = loadgen.traffic(mix, seed, seconds, su.conds, slots)
     watch = check.plan(
-        loadgen.candidates(mix, seed, seconds, d.classes, slots),
+        loadgen.candidates(mix, seed, seconds, su.conds, slots),
         int(cfg["check"]["sample"]), seed)
 
     annotate, on_tick, tracer = None, None, None
@@ -138,13 +141,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         tracer = trace_reduce.Recorder(TRACE_DIR,
                                        max(0.0, seconds - TRACE_S))
         annotate, on_tick = tracer.annotate, tracer.tick
-    jax.block_until_ready(eng.x)
+    srv.block()
     setup_s = time.perf_counter() - t_start
     counter = su.counter
     log(f"set-up {setup_s:.1f}s ({counter.compiles} compiles, "
         f"{counter.compile_s:.1f}s; {counter.cache_hits} cache hits; "
         f"cache {su.cache_dir})")
-    w = drive(eng, traffic, seconds, counter, backlog_depth=depth,
+    w = drive(srv, traffic, seconds, counter, backlog_depth=depth,
               annotate=annotate, on_tick=on_tick, watch=watch)
     mem_peak = max((x.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for x in su.devs) or None
@@ -153,9 +156,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         f"{1e3 * dt:.1f} ms at {at:.2f} s ({what})"
         for dt, at, what in w.stalls))
 
-    run = RunData(cell=cell, dims=d, shape=flops.shape_of(d, algo),
+    run = RunData(cell=cell, dims=d, shape=fam.shape_of(d, algo),
                   algo=algo, window=w, setup_s=setup_s, peak=su.peak,
-                  memory_peak_bytes=mem_peak, trace=summary)
+                  memory_peak_bytes=mem_peak, trace=summary, family=fam)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = metric_reader(m["name"], cell.root)(run)
@@ -165,15 +168,15 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     # the check: the window's outputs against the plain reference, once
     # the program's state is freed
     sample = check.sampled(w.requests, watch)
-    del eng
-    p32 = reference.to_f32(params)
+    del srv
+    p32 = fam.to_f32(params)
     del params
     t0 = time.perf_counter()
-    ref = check.reference_outputs(p32, d, algo, sample)
-    values = {"first_step_gap": math.inf, "gated_step_gap": math.inf}
+    ref = fam.reference_outputs(p32, d, algo, sample)
+    values = {k: math.inf for k in cfg["check"]["limits"]}
     if sample:
-        values = check.gaps(d, sample, check.served_outputs(sample), ref)
-    breaks, unread = check.rule_breaks(sample, algo)
+        values = fam.gaps(d, sample, check.served_outputs(sample), ref)
+    breaks, unread = fam.rule_breaks(sample, algo)
     non_finite = sum(1 for r in w.requests if r.latents is not None
                      and not np.isfinite(r.latents).all())
     unfinished = sum(1 for r in w.requests if r.latents is None)
@@ -192,6 +195,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         f"({sum(len(check.gated_steps(r)) for r in sample)} gated steps, "
         f"{unread} rows whose decisions the state does not show) in "
         f"{time.perf_counter() - t0:.1f}s")
+    if w.replicas:
+        log("requests admitted by replica: "
+            + ", ".join(str(n) for n in w.replicas) + "; compared: "
+            + ", ".join(str(sum(r.replica == i for r in sample))
+                        for i in range(len(w.replicas))))
     for name, v in shown.items():
         log(f"check {name} = {v['value']!r} (limit {v['limit']!r})")
 

@@ -13,9 +13,10 @@ instruction (for an output fusion, that of its matmul), else that of its
 fused computation's root, else (a root the compiler left unnamed, such as
 a scatter) that of the computation's last named instruction.
 
-``serve_step_text`` compiles the step that ``bench/window.build`` makes for
-a cell, from shapes alone; with the persistent compilation cache on, this
-reads the run's own executable back.  An op of the engine's other programs
+``serve_step_text`` compiles the step of the engine that the cell's model
+family builds (``build`` of ``bench/families/<family>.py``), from shapes
+alone; with the persistent compilation cache on, this reads the run's own
+executable back.  An op of the engine's other programs
 (admission, slot reset, slot copy) that shares its instruction name with an
 op of the step is counted as the step's op.
 """
@@ -109,13 +110,14 @@ def serve_step_text(cell) -> str:
     import jax
     import jax.numpy as jnp
 
-    from bench import loadgen, weights
-    from bench.window import build
+    from bench import loadgen
+    from bench.spec import family
 
     cfg = cell.config
-    d = weights.dims_of(cfg)
-    params = jax.eval_shape(lambda: weights.make_params(d, 0, cfg["dtype"]))
-    eng = build(cfg, params, loadgen.max_steps(cell.mix))
+    fam = family(cfg, cell.root)
+    d = fam.dims_of(cfg)
+    params = jax.eval_shape(lambda: fam.make_params(d, 0, cfg["dtype"]))
+    eng, _ = fam.build(cfg, params, loadgen.max_steps(cell.mix))
 
     def shapes(tree):
         return jax.tree.map(

@@ -6,11 +6,11 @@ rates, each for a window, in one process.
     python3 bench/sweep.py --workload <name> --slots 4,8,16,32 --seconds 20
 
 A rate is sustained when the queue does not grow over the window: no more
-than ``slots`` requests wait at the close, and the second half of the
-window's requests wait no longer than the first half's plus one service
-time; and when queueing stays out of the median: the median latency is
-within 1.5 service times.  The knee is the highest sustained rate; the
-cell's mix is then set to about four fifths of it.  A backlog mix is
+than ``slots`` requests per engine wait at the close, and the second half
+of the window's requests wait no longer than the first half's plus one
+service time; and when queueing stays out of the median: the median
+latency is within 1.5 service times.  The knee is the highest sustained
+rate; the cell's mix is then set to about four fifths of it.  A backlog mix is
 served once per slot count.  Writes ``chiprun_out/sweep_<name>.json``.
 """
 from __future__ import annotations
@@ -58,24 +58,24 @@ def main() -> int:
     args = ap.parse_args()
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from bench import loadgen, weights
+    from bench import loadgen
     from bench.run import serving, set_up
     from bench.spec import load_cell
     from bench.window import drive
 
     cell = load_cell(args.workload)
     su = set_up(cell)
-    mix, d = cell.mix, su.dims
-    params = weights.make_params(d, args.seed, cell.config["dtype"])
+    mix = cell.mix
+    params = su.family.make_params(su.dims, args.seed, cell.config["dtype"])
     rows = []
     for slots in [int(s) for s in args.slots.split(",") if s] or [
             int(cell.config["slots"])]:
         cfg = dict(cell.config, slots=slots)
-        eng = serving(cfg, params, su.max_steps)
+        srv = serving(cfg, params, su.max_steps, root=cell.root)
         if mix["arrival"] == "backlog":
             stream, depth = loadgen.traffic(mix, args.seed, args.seconds,
-                                            d.classes, slots)
-            w = drive(eng, stream, args.seconds, su.counter,
+                                            su.conds, slots)
+            w = drive(srv, stream, args.seconds, su.counter,
                       backlog_depth=depth)
             rows.append(window_row(cfg, w, w.requests, args.seconds,
                                    su.dev))
@@ -83,19 +83,21 @@ def main() -> int:
         for rate in (float(r) for r in args.rates.split(",") if r):
             m = dict(mix, rate=rate)
             m.pop("segments", None)
-            reqs = loadgen.poisson(m, args.seed, args.seconds, d.classes)
-            eng.reset_clock()
-            w = drive(eng, reqs, args.seconds, su.counter)
+            reqs = loadgen.poisson(m, args.seed, args.seconds, su.conds)
+            srv.reset_clock()
+            w = drive(srv, reqs, args.seconds, su.counter)
             row = dict(window_row(cfg, w, reqs, args.seconds, su.dev),
                        rate=rate)
+            if w.replicas:
+                row["replicas"] = w.replicas
             row["sustained"] = bool(
-                row["queued_at_close"] <= slots
+                row["queued_at_close"] <= slots * len(srv.engines)
                 and row["wait_second_half_ms"]
                 <= row["wait_first_half_ms"] + row["service_ms"]
                 and row["latency_p50_ms"] <= 1.5 * row["service_ms"])
             rows.append(row)
             print(json.dumps(row), flush=True)
-        del eng
+        del srv
     knee = {}                   # slots -> highest rate, all below sustained
     for r in sorted((r for r in rows if "rate" in r),
                     key=lambda r: (r["slots"], r["rate"])):

@@ -20,6 +20,7 @@ sys.path[:0] = [str(HERE.parents[1]), str(HERE)]
 import tiny_cell  # noqa: E402
 from bench import check, reference, weights  # noqa: E402
 from bench.calibrate import readings  # noqa: E402
+from bench.families import dit  # noqa: E402
 from bench.run import run_cell, serving, set_up  # noqa: E402
 from bench.spec import load_cell  # noqa: E402
 from repro.core import statcache  # noqa: E402
@@ -153,9 +154,9 @@ def test_float8_control_fails_the_cached_steps(cell, seed):
     own inputs at the same cached steps, reads past the limit."""
     su = set_up(cell, require_chip=False)
     cfg = cell.config
-    eng = serving(cfg, weights.make_params(su.dims, seed, cfg["dtype"]),
+    srv = serving(cfg, weights.make_params(su.dims, seed, cfg["dtype"]),
                   su.max_steps)
-    row = readings(cell, su, eng, seed, SECONDS, control=True)
+    row = readings(cell, su, srv, seed, SECONDS, control=True)
     assert row["gated_steps"] >= 3, row
     ok, _ = check.verdict(row["control"], cfg["check"]["limits"])
     assert not ok, row
@@ -170,9 +171,9 @@ def test_float8_control_is_not_correct(cell, seed):
     algo = reference.algo_of(cfg)
     p32 = reference.to_f32(weights.make_params(d, seed, cfg["dtype"]))
     reqs = [r for r in tiny_requests(cell, seed)][:3]
-    ref = check.reference_outputs(p32, d, algo, reqs)
-    ctl = check.reference_outputs(p32, d, algo, reqs, quant=True)
-    gap = check.gaps(d, reqs, ctl, ref)["first_step_gap"]
+    ref = dit.reference_outputs(p32, d, algo, reqs)
+    ctl = dit.reference_outputs(p32, d, algo, reqs, quant=True)
+    gap = dit.gaps(d, reqs, ctl, ref)["first_step_gap"]
     ok, _ = check.verdict({"first_step_gap": gap}, cfg["check"]["limits"])
     assert not ok, gap
 
@@ -186,7 +187,7 @@ def test_reference_outputs_are_finite(cell):
     cfg = cell.config
     d = weights.dims_of(cfg)
     p32 = reference.to_f32(weights.make_params(d, 9, cfg["dtype"]))
-    out = check.reference_outputs(p32, d, reference.algo_of(cfg),
-                                  tiny_requests(cell, 9)[:2])
+    out = dit.reference_outputs(p32, d, reference.algo_of(cfg),
+                                tiny_requests(cell, 9)[:2])
     assert all(np.isfinite(a).all() for steps in out.values()
                for a in steps.values())

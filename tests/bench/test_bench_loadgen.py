@@ -15,7 +15,7 @@ MIX = {"arrival": "poisson", "rate": 5.0, "steps": {"20": 1, "50": 3},
 
 
 def fields(reqs):
-    return [(r.due, r.label, r.steps, r.guidance, r.noise_seed)
+    return [(r.due, r.cond, r.steps, r.guidance, r.noise_seed)
             for r in reqs]
 
 
@@ -34,7 +34,7 @@ def test_rate_and_shares_are_exact(seed):
     assert np.all(np.diff(due) >= 0) and 0 <= due[0] and due[-1] < 40.0
     assert sorted(r.steps for r in reqs) == [20] * 50 + [50] * 150
     assert sum(r.guidance == 1.0 for r in reqs) == 100
-    assert all(0 <= r.label < 1000 for r in reqs)
+    assert all(0 <= r.cond < 1000 for r in reqs)
     # exponential gaps: their spread matches their mean
     gaps = np.diff(due)
     assert 0.8 < np.std(gaps) / np.mean(gaps) < 1.2

@@ -14,7 +14,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import reference, weights  # noqa: E402
-from bench.window import model_config  # noqa: E402
+from bench.families.dit import model_config  # noqa: E402
 
 SMALL = {"model": "dit-xl2", "depth": 3, "hidden_size": 64, "num_heads": 4,
          "patch_size": 2, "input_size": 8, "in_channels": 4,
